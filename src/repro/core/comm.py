@@ -29,8 +29,8 @@ Implementations:
               one COLLECTIVE byte exchange (``distributed.sharding.
               PpermuteByteTransport`` -- a shard_map ppermute over a 1-D
               host mesh), because jax has no independent pairwise sends.
-              On this single-process container the schedule is empty and
-              it degenerates to SoloComm semantics.
+              With one process the schedule is empty and it
+              degenerates to SoloComm semantics.
 
 Point-to-point transports advertise ``has_p2p``; the base ``reduce_tree``
 then runs the distributed schedule directly on ``send``/``recv``.
@@ -55,8 +55,12 @@ collective's pairing exactly).
 from __future__ import annotations
 
 import queue
+import struct
 import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
+
+#: (rank, payload length) header of one JaxComm.gather_tree frame
+_GATHER_FRAME = struct.Struct("<IQ")
 
 #: seconds a ThreadComm recv waits before concluding the peer is gone
 #: (the default; per-call ``recv(..., timeout=)`` and the degraded
@@ -524,9 +528,11 @@ class JaxComm(Comm):
     them unchanged and the result is byte-identical to every other
     topology (the schedule is :func:`reduce_rounds`).
 
-    On a single-process runtime the schedule is empty and everything
-    degenerates to SoloComm semantics, which is what this container
-    exercises; the transport can be injected for testing.
+    ``gather_tree`` frames each rank's bytes as ``(rank, length, payload)``
+    so every hop carries one byte string, which is what the transport
+    moves.  On a single-process runtime the schedule is empty and
+    everything degenerates to SoloComm semantics; the transport can be
+    injected for testing.
     """
 
     def __init__(self, transport: Optional[Any] = None) -> None:
@@ -548,6 +554,26 @@ class JaxComm(Comm):
             return obj
         return reduce_tree_via_exchange(self.rank, self.size, obj, fn,
                                         self._xport().exchange, root=root)
+
+    def gather_tree(self, obj, root=0):
+        if self.size == 1:
+            return [obj]
+        if not isinstance(obj, bytes):
+            raise TypeError("JaxComm.gather_tree carries bytes payloads, "
+                            f"got {type(obj).__name__}")
+        merged = self.reduce_tree(
+            _GATHER_FRAME.pack(self.rank, len(obj)) + obj,
+            lambda a, b: a + b, root=root)
+        if merged is None:
+            return None
+        out: Dict[int, bytes] = {}
+        pos = 0
+        while pos < len(merged):
+            r, n = _GATHER_FRAME.unpack_from(merged, pos)
+            pos += _GATHER_FRAME.size
+            out[r] = merged[pos:pos + n]
+            pos += n
+        return [out[r] for r in sorted(out)]
 
     def vote_any(self, flag):
         if self.size == 1:

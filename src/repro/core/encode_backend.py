@@ -16,8 +16,9 @@ implementations:
 
 ``pallas``
     The TPU kernels under ``repro.kernels`` (``delta_encode``,
-    ``grammar_stats``), run in ``interpret=True`` mode when no accelerator
-    is attached so CPU-only CI still exercises the kernel arithmetic.
+    ``grammar_stats``), compiled on the accelerator.  Where JAX's backend
+    is the CPU (the test suite) they run under the Pallas interpreter, so
+    CPU-only CI still exercises the kernel arithmetic.
 
 ``auto`` (the default) crosses over by batch size: tiny batches stay on
 the Python loop (below NumPy's fixed per-call overhead), everything else
@@ -47,6 +48,7 @@ PALLAS_MIN_BATCH = 1 << 16   # below: kernel launch + transfer dominates
 
 _U64_MAX = (1 << 64) - 1
 _I32_SAFE = 1 << 31
+_I32_DIFF_SAFE = 1 << 30     # int32 differences of such values never wrap
 
 _default_backend = "auto"
 _accel: Optional[bool] = None
@@ -68,21 +70,23 @@ def set_default_backend(backend: str) -> None:
 
 
 def has_accelerator() -> bool:
-    """True when jax sees a non-CPU device (memoized; False when jax is
-    missing entirely, so ``auto`` degrades to numpy)."""
+    """True when JAX's default backend is not the CPU (memoized).  False
+    only when jax is not installed, so ``auto`` then stays on numpy; a
+    JAX runtime that fails to come up raises here."""
     global _accel
     if _accel is None:
         try:
             import jax
-            _accel = any(d.platform != "cpu" for d in jax.devices())
-        except Exception:
+        except ImportError:
             _accel = False
+        else:
+            _accel = jax.default_backend() != "cpu"
     return _accel
 
 
 def interpret_mode() -> bool:
-    """Kernels run under the Pallas interpreter when no accelerator is
-    attached -- CPU-only CI exercises the kernel arithmetic this way."""
+    """Kernels run under the Pallas interpreter only where JAX's backend
+    is the CPU (the test suite); on an accelerator they are compiled."""
     return not has_accelerator()
 
 
@@ -319,17 +323,15 @@ def fit_classify(V: np.ndarray, backend: Optional[str] = None
                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Per-column (const_mask, linear_mask, first_diff) of a (C, R) int64
     value matrix with R >= 2 -- the vectorized core of the rank-linear
-    fitter.  The pallas path runs one kernel call over padded column tiles
-    and falls back to NumPy when values do not fit int32 (TPU-native
-    width)."""
-    if backend == "pallas" and np.abs(V).max(initial=0) < _I32_SAFE:
+    fitter.  The pallas path runs one kernel call over column tiles and
+    falls back to NumPy when the kernel's int32 differences could wrap."""
+    if backend == "pallas" and np.abs(V).max(initial=0) < _I32_DIFF_SAFE:
         import jax.numpy as jnp
         from ..kernels.delta_encode.ops import fit_columns
         flags, d0 = fit_columns(jnp.asarray(V.astype(np.int32)),
                                 interpret=interpret_mode())
-        flags = np.asarray(flags)[: V.shape[0]]
-        d0 = np.asarray(d0)[: V.shape[0]].astype(np.int64)
-        return flags == 1, flags == 2, d0
+        flags = np.asarray(flags)
+        return flags == 1, flags == 2, np.asarray(d0).astype(np.int64)
     d = V[:, 1:] - V[:, :-1]
     const = (d == 0).all(axis=1)
     linear = (d == d[:, :1]).all(axis=1) & (d[:, 0] != 0)

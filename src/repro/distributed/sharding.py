@@ -27,24 +27,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 _state = threading.local()
 
 
-def get_shard_map():
-    """Version-tolerant ``shard_map`` accessor.
-
-    ``jax.shard_map`` is the public name on new jax releases;  older ones
-    (<= 0.4.x) only ship ``jax.experimental.shard_map.shard_map``.  All
-    repo code (and test subprocess snippets) goes through this accessor.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    from jax.experimental.shard_map import shard_map as sm_exp
-
-    return sm_exp
-
-
-#: resolved once at import; usable as ``shard_map(f, mesh=..., ...)``
-shard_map = get_shard_map()
-
 LOGICAL_TO_PHYSICAL: Dict[str, Tuple[str, ...]] = {
     "batch": ("pod", "data"),
     "tp": ("model",),
@@ -209,12 +191,15 @@ class PpermuteByteTransport:
         n = 0 if payload is None else len(payload)
         cap = int(multihost_utils.process_allgather(
             np.asarray([n], np.int64)).max()) + _LEN_HEADER
+        # a power of two, so a finalize compiles the exchange for a few
+        # array sizes rather than one per payload length
+        cap = 1 << max(6, (cap - 1).bit_length())
         local = pack_bytes_array(payload, cap)[None, :]
         mesh = self._host_mesh()
         spec_ = P(HOST_AXIS, None)
         global_arr = multihost_utils.host_local_array_to_global_array(
             local, mesh, spec_)
-        shifted = get_shard_map()(
+        shifted = jax.shard_map(
             lambda x: jax.lax.ppermute(x, HOST_AXIS, perm),
             mesh=mesh, in_specs=spec_, out_specs=spec_)(global_arr)
         back = multihost_utils.global_array_to_host_local_array(

@@ -2,9 +2,10 @@
 
 The tracing pipeline's only arithmetic-dense stage: millions of u32 ticks
 -> first-order delta -> zigzag, before zlib.  On a real pod the staging
-buffers can be encoded on-device before DMA to host.  Grid = (n_blocks,)
-sequential; VMEM scratch carries the previous block's last element so the
-cross-block delta is exact.
+buffers can be encoded on-device before DMA to host.  The stream is laid
+out in lane-dense (rows, 128) tiles (``kernels.stream_tiles``); each grid
+step gets the element before its block through scalar prefetch, so the
+cross-block delta is exact and blocks are independent.
 
 Arithmetic is 32-bit two's-complement: deltas wrap mod 2^32, which matches
 the reference encoder bit-for-bit whenever |delta| < 2^31 (tick deltas are
@@ -14,45 +15,67 @@ through the mod-2^32 decoder otherwise.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..stream_tiles import (
+    LANES,
+    PARALLEL,
+    block_carries,
+    block_rows,
+    from_tiles,
+    prev_elements,
+    to_tiles,
+)
 
-def _delta_kernel(x_ref, o_ref, prev_ref):
-    i = pl.program_id(0)
-    x = x_ref[...].astype(jnp.int32)               # bit-pattern reinterpret
 
-    @pl.when(i == 0)
-    def _first():
-        prev_ref[0] = jnp.array(0, jnp.int32)
+def _zigzag_delta(x_ref, carry_ref):
+    x = x_ref[...]                                  # (R, 128) int32
+    delta = x - prev_elements(x, carry_ref[pl.program_id(0)])  # wraps
+    return (delta << 1) ^ (delta >> 31)
 
-    prev = prev_ref[0]
-    shifted = jnp.concatenate([prev[None], x[:-1]])
-    first_mask = (i == 0) & (jax.lax.iota(jnp.int32, x.shape[0]) == 0)
-    delta = jnp.where(first_mask, x, x - shifted)  # wraps mod 2^32
-    zz = (delta << 1) ^ (delta >> 31)
-    o_ref[...] = zz.astype(jnp.uint32)
-    prev_ref[0] = x[-1]
+
+def _delta_kernel(carry_ref, x_ref, o_ref):
+    o_ref[...] = _zigzag_delta(x_ref, carry_ref)
+
+
+def _stream_call(kernel, ticks, block, n_out_planes, interpret):
+    """Run a delta kernel over flat u32 ``ticks`` in (rows, 128) tiles;
+    the first element's predecessor is 0, so it is kept as is."""
+    n = ticks.shape[0]
+    rows = block_rows(n, block)
+    tiles, nb = to_tiles(jax.lax.bitcast_convert_type(ticks, jnp.int32),
+                         rows, 0)
+    tile_spec = pl.BlockSpec((rows, LANES), lambda i, c: (i, 0))
+    out_specs = [tile_spec]
+    out_shape = [jax.ShapeDtypeStruct(tiles.shape, jnp.int32)]
+    if n_out_planes:
+        out_specs += [tile_spec, pl.BlockSpec((n_out_planes, rows, LANES),
+                                              lambda i, c: (0, i, 0))]
+        out_shape += [jax.ShapeDtypeStruct(tiles.shape, jnp.int32),
+                      jax.ShapeDtypeStruct((n_out_planes,) + tiles.shape,
+                                           jnp.int32)]
+    outs = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(nb,), in_specs=[tile_spec],
+            out_specs=out_specs),
+        out_shape=out_shape,
+        compiler_params=PARALLEL,
+        interpret=interpret,
+    )(block_carries(tiles, rows, 0), tiles)
+    return [from_tiles(o, n) for o in outs]
 
 
 def delta_zigzag_pallas(ticks: jax.Array, *, block: int = 4096,
                         interpret: bool = False) -> jax.Array:
     """ticks: flat u32 array -> zigzag'd u32 deltas (first element kept)."""
-    n = ticks.shape[0]
-    blk = min(block, n)
-    while n % blk:
-        blk -= 1
-    return pl.pallas_call(
-        _delta_kernel,
-        grid=(n // blk,),
-        in_specs=[pl.BlockSpec((blk,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((blk,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.uint32),
-        scratch_shapes=[pltpu.VMEM((1,), jnp.int32)],
-        interpret=interpret,
-    )(ticks)
+    (zz,) = _stream_call(_delta_kernel, ticks, block, 0, interpret)
+    return jax.lax.bitcast_convert_type(zz, jnp.uint32)
 
 
 # ---------------------------------------------------------------------------
@@ -69,67 +92,40 @@ def delta_zigzag_pallas(ticks: jax.Array, *, block: int = 4096,
 
 
 def _varint_planes(zz, len_ref, plane_ref, n_planes):
+    """Byte counts and byte planes of u32 values ``zz``; the planes hold
+    byte j of every element with its continuation bit set."""
     ln = jnp.ones(zz.shape, jnp.int32)
     for k in range(1, n_planes):
         ln = ln + (zz >= jnp.uint32(1 << (7 * k))).astype(jnp.int32)
     len_ref[...] = ln
     for j in range(n_planes):
-        b = (zz >> jnp.uint32(7 * j)).astype(jnp.uint32) & jnp.uint32(0x7F)
+        b = (zz >> jnp.uint32(7 * j)) & jnp.uint32(0x7F)
         b = jnp.where(j < ln - 1, b | jnp.uint32(0x80), b)
-        plane_ref[j, :] = b.astype(jnp.int32)
+        plane_ref[j] = b.astype(jnp.int32)
 
 
-def _delta_varint_kernel(x_ref, zz_ref, len_ref, plane_ref, prev_ref):
-    i = pl.program_id(0)
-    x = x_ref[...].astype(jnp.int32)
-
-    @pl.when(i == 0)
-    def _first():
-        prev_ref[0] = jnp.array(0, jnp.int32)
-
-    prev = prev_ref[0]
-    shifted = jnp.concatenate([prev[None], x[:-1]])
-    first_mask = (i == 0) & (jax.lax.iota(jnp.int32, x.shape[0]) == 0)
-    delta = jnp.where(first_mask, x, x - shifted)
-    zz = ((delta << 1) ^ (delta >> 31)).astype(jnp.uint32)
+def _delta_varint_kernel(carry_ref, x_ref, zz_ref, len_ref, plane_ref):
+    zz = _zigzag_delta(x_ref, carry_ref)
     zz_ref[...] = zz
-    prev_ref[0] = x[-1]
-    _varint_planes(zz, len_ref, plane_ref, 5)
+    _varint_planes(jax.lax.bitcast_convert_type(zz, jnp.uint32), len_ref,
+                   plane_ref, 5)
 
 
 def delta_zigzag_varint_pallas(ticks: jax.Array, *, block: int = 4096,
                                interpret: bool = False):
     """Fused encode: flat u32 ticks -> (zigzag u32, varint byte counts,
     (5, n) byte planes).  A u32 varint is at most 5 bytes."""
-    n = ticks.shape[0]
-    blk = min(block, n)
-    while n % blk:
-        blk -= 1
-    return pl.pallas_call(
-        _delta_varint_kernel,
-        grid=(n // blk,),
-        in_specs=[pl.BlockSpec((blk,), lambda i: (i,))],
-        out_specs=[
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((5, blk), lambda i: (0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.uint32),
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((5, n), jnp.int32),
-        ],
-        scratch_shapes=[pltpu.VMEM((1,), jnp.int32)],
-        interpret=interpret,
-    )(ticks)
+    zz, lens, planes = _stream_call(_delta_varint_kernel, ticks, block, 5,
+                                    interpret)
+    return jax.lax.bitcast_convert_type(zz, jnp.uint32), lens, planes
 
 
 def _uvarint64_kernel(lo_ref, hi_ref, len_ref, plane_ref):
     # u64 values arrive as (lo, hi) u32 planes -- Pallas TPU has no i64
     # lanes.  Byte j covers bits 7j..7j+6: for 7j < 32 that straddles the
     # lo/hi boundary, above it reads hi alone.  A u64 varint is <= 10 bytes.
-    lo = lo_ref[...].astype(jnp.uint32)
-    hi = hi_ref[...].astype(jnp.uint32)
+    lo = lo_ref[...]
+    hi = hi_ref[...]
     ln = jnp.ones(lo.shape, jnp.int32)
     for k in range(1, 10):
         s = 7 * k
@@ -149,7 +145,7 @@ def _uvarint64_kernel(lo_ref, hi_ref, len_ref, plane_ref):
         else:
             b = (hi >> jnp.uint32(s - 32)) & jnp.uint32(0x7F)
         b = jnp.where(j < ln - 1, b | jnp.uint32(0x80), b)
-        plane_ref[j, :] = b.astype(jnp.int32)
+        plane_ref[j] = b.astype(jnp.int32)
 
 
 def uvarint_encode64_pallas(lo: jax.Array, hi: jax.Array, *,
@@ -157,26 +153,24 @@ def uvarint_encode64_pallas(lo: jax.Array, hi: jax.Array, *,
     """u64 values as (lo, hi) u32 arrays -> (byte counts, (10, n) byte
     planes) for the host scatter.  Elementwise; blocks are independent."""
     n = lo.shape[0]
-    blk = min(block, n)
-    while n % blk:
-        blk -= 1
-    return pl.pallas_call(
+    rows = block_rows(n, block)
+    lo_t, nb = to_tiles(lo.astype(jnp.uint32), rows, 0)
+    hi_t, _ = to_tiles(hi.astype(jnp.uint32), rows, 0)
+    tile_spec = pl.BlockSpec((rows, LANES), lambda i: (i, 0))
+    lens, planes = pl.pallas_call(
         _uvarint64_kernel,
-        grid=(n // blk,),
-        in_specs=[
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((blk,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((10, blk), lambda i: (0, i)),
-        ],
+        grid=(nb,),
+        in_specs=[tile_spec, tile_spec],
+        out_specs=[tile_spec,
+                   pl.BlockSpec((10, rows, LANES), lambda i: (0, i, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((n,), jnp.int32),
-            jax.ShapeDtypeStruct((10, n), jnp.int32),
+            jax.ShapeDtypeStruct(lo_t.shape, jnp.int32),
+            jax.ShapeDtypeStruct((10,) + lo_t.shape, jnp.int32),
         ],
+        compiler_params=PARALLEL,
         interpret=interpret,
-    )(lo, hi)
+    )(lo_t, hi_t)
+    return from_tiles(lens, n), from_tiles(planes, n)
 
 
 # ---------------------------------------------------------------------------
@@ -186,41 +180,47 @@ def uvarint_encode64_pallas(lo: jax.Array, hi: jax.Array, *,
 # A column fits the rank-linear model iff its first-order deltas are
 # constant -- the same delta core as the timestamp kernel, batched over
 # column tiles.  flag: 0 = no fit, 1 = constant, 2 = linear (nonzero
-# slope); d0 = the first delta (the slope when linear).
+# slope); d0 = the first delta (the slope when linear).  The kernel sees
+# the matrix transposed, (ranks, columns), so columns fill the lanes and
+# the rank-to-rank delta is a roll along sublanes.
 
 
-def _fit_columns_kernel(v_ref, flag_ref, d0_ref):
-    v = v_ref[...]                                  # (blk, R) int32
-    d = v[:, 1:] - v[:, :-1]
-    const = (d == 0).all(axis=1)
-    linear = (d == d[:, :1]).all(axis=1) & (d[:, 0] != 0)
-    flag_ref[...] = jnp.where(const, 1,
-                              jnp.where(linear, 2, 0)).astype(jnp.int32)
-    d0_ref[...] = d[:, 0]
+def _fit_columns_kernel(v_ref, flag_ref, d0_ref, *, n_ranks: int):
+    v = v_ref[...]                                  # (ranks, blk) int32
+    d = v - pltpu.roll(v, 1, 0)                     # [r] = v[r] - v[r-1]
+    r = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
+    valid = (r >= 1) & (r < n_ranks)
+    d0 = jnp.sum(jnp.where(r == 1, d, 0), axis=0, keepdims=True)
+    n_moves = jnp.sum(jnp.where(valid & (d != 0), 1, 0), axis=0,
+                      keepdims=True)
+    n_bends = jnp.sum(jnp.where(valid & (d != d0), 1, 0), axis=0,
+                      keepdims=True)
+    flag_ref[...] = jnp.where(n_moves == 0, 1,
+                              jnp.where((n_bends == 0) & (d0 != 0), 2, 0))
+    d0_ref[...] = d0
 
 
-def fit_columns_pallas(V: jax.Array, *, block: int = 256,
+def fit_columns_pallas(V: jax.Array, *, block: int = 128,
                        interpret: bool = False):
     """(C, R) int32 column matrix (R >= 2) -> per-column (flags, first
-    deltas) in one pallas_call over padded column tiles.  Rows are padded
-    to a block multiple with zeros (classified constant; callers slice)."""
+    deltas) in one pallas_call over column tiles.  Deltas are int32, so
+    callers keep ``|V| < 2^30``."""
     c, r = V.shape
-    blk = min(block, c)
-    pad = (-c) % blk
-    if pad:
-        V = jnp.concatenate([V, jnp.zeros((pad, r), V.dtype)], axis=0)
-    cp = c + pad
-    return pl.pallas_call(
-        _fit_columns_kernel,
+    blk = max(LANES, -(-block // LANES) * LANES)
+    cp = -(-c // blk) * blk
+    rp = -(-r // 8) * 8
+    T = jnp.pad(V.astype(jnp.int32).T, ((0, rp - r), (0, cp - c)))
+    col_spec = pl.BlockSpec((1, blk), lambda i: (0, i))
+    flags, d0 = pl.pallas_call(
+        functools.partial(_fit_columns_kernel, n_ranks=r),
         grid=(cp // blk,),
-        in_specs=[pl.BlockSpec((blk, r), lambda i: (i, 0))],
-        out_specs=[
-            pl.BlockSpec((blk,), lambda i: (i,)),
-            pl.BlockSpec((blk,), lambda i: (i,)),
-        ],
+        in_specs=[pl.BlockSpec((rp, blk), lambda i: (0, i))],
+        out_specs=[col_spec, col_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((cp,), jnp.int32),
-            jax.ShapeDtypeStruct((cp,), jnp.int32),
+            jax.ShapeDtypeStruct((1, cp), jnp.int32),
+            jax.ShapeDtypeStruct((1, cp), jnp.int32),
         ],
+        compiler_params=PARALLEL,
         interpret=interpret,
-    )(V)
+    )(T)
+    return flags[0, :c], d0[0, :c]

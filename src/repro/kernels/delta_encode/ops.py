@@ -36,7 +36,7 @@ def uvarint_encode64(lo, hi, *, block: int = 4096,
 
 
 @partial(jax.jit, static_argnames=("block", "interpret"))
-def fit_columns(V, *, block: int = 256, interpret: bool = False):
+def fit_columns(V, *, block: int = 128, interpret: bool = False):
     """(C, R) int32 columns -> (flags, first deltas); flag 1 = constant,
-    2 = rank-linear, 0 = no fit.  Outputs padded to a block multiple."""
+    2 = rank-linear, 0 = no fit."""
     return fit_columns_pallas(V, block=block, interpret=interpret)

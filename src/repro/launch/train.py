@@ -3,10 +3,10 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-0.5b \
         --smoke --steps 50 --batch 4 --seq 64 --trace-dir /tmp/trace
 
-On a real multi-host pod this process runs once per host
-(jax.distributed.initialize picks rank/coordinator from env); on this
-container it drives the same code path single-host.  ``--smoke`` selects
-the reduced config so the example trains in CPU-minutes.
+It trains in one process on the devices JAX finds.  ``--smoke`` selects
+the reduced config so the example trains in CPU-minutes.  Checkpoints go
+to ``--ckpt-dir``, by default ``<trace-dir>/ckpt`` or else a fresh
+temporary directory, so a rerun never resumes from another run's state.
 """
 
 from __future__ import annotations
@@ -14,12 +14,14 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import tempfile
 
 from ..configs import get_config, get_smoke_config
 from ..core.recorder import RecorderConfig, session
 from ..data import SyntheticConfig, synthetic_batch
 from ..optim import AdamWConfig
 from ..train import Trainer, TrainerConfig
+from .compile_cache import enable_compile_cache
 
 
 def main() -> None:
@@ -31,13 +33,19 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--lr", type=float, default=1e-3)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_train_ckpt")
+    ap.add_argument("--ckpt-dir", default=None,
+                    help="default: <trace-dir>/ckpt, else a fresh temp dir")
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--async-ckpt", action="store_true")
     ap.add_argument("--accum", type=int, default=1)
     ap.add_argument("--trace-dir", default=None,
                     help="Recorder trace output (enables tracing)")
     args = ap.parse_args()
+    if args.ckpt_dir is None:
+        args.ckpt_dir = (os.path.join(args.trace_dir, "ckpt")
+                         if args.trace_dir
+                         else tempfile.mkdtemp(prefix="repro_train_ckpt_"))
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     dcfg = SyntheticConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,

@@ -602,7 +602,7 @@ def moe_apply(p: Params, cfg: ModelConfig, x: jax.Array
     ep = axis_size("tp")
     use_ep = ("model" in axes) and ep > 1 and S % ep == 0
     if use_ep:
-        from ..distributed.sharding import current_mesh, get_shard_map
+        from ..distributed.sharding import current_mesh
         from jax.sharding import PartitionSpec as P
         mesh = current_mesh()
         dp_axes = tuple(a for a in ("pod", "data") if a in axes)
@@ -616,7 +616,7 @@ def moe_apply(p: Params, cfg: ModelConfig, x: jax.Array
                 aux = lax.pmean(aux, axis_name=dp_axes)
             return y.reshape(xb.shape), aux
 
-        y, aux = get_shard_map()(
+        y, aux = jax.shard_map(
             blk, mesh=mesh,
             in_specs=(P(dp_axes or None, "model", None), P(None, None),
                       P("model", None, None), P("model", None, None),

@@ -96,17 +96,23 @@ class Trainer:
     # -- state ----------------------------------------------------------------
 
     def init_state(self) -> None:
-        """Fresh init or auto-resume from the newest valid checkpoint."""
-        params = self.model.init_params(jax.random.PRNGKey(self.tcfg.seed))
-        state = adamw_init(params)
-        restored = self.engine.restore_latest(jax.tree.map(np.asarray, state))
+        """Fresh init or auto-resume from the newest valid checkpoint.
+
+        The checkpoint is read against the state's shapes alone, so a
+        resume never holds a fresh state and the restored one on the
+        device at the same time."""
+        def fresh():
+            return adamw_init(self.model.init_params(
+                jax.random.PRNGKey(self.tcfg.seed)))
+
+        restored = self.engine.restore_latest(jax.eval_shape(fresh))
         if restored is not None:
             tree, manifest = restored
             self.state = jax.tree.map(jax.numpy.asarray, tree)
             self.start_step = int(manifest["meta"].get("next_step",
                                                        manifest["step"]))
         else:
-            self.state = state
+            self.state = fresh()
             self.start_step = 0
 
     # -- loop -------------------------------------------------------------------

@@ -296,6 +296,67 @@ def test_pack_bytes_array_roundtrip():
         pack_bytes_array(b"xxxx", 5)  # cannot hold payload + header
 
 
+class _BytesOnlyTransport:
+    """Stand-in for ``PpermuteByteTransport`` across threads: payloads go
+    through the same fixed-size uint8 packing, so anything but bytes
+    fails as it would on the chips."""
+
+    def __init__(self, size):
+        self.slots = [None] * size
+        self.barrier = threading.Barrier(size, timeout=60)
+
+    def exchange_for(self, rank):
+        from repro.distributed.sharding import (pack_bytes_array,
+                                                unpack_bytes_array)
+
+        def exchange(payload, perm):
+            n = 0 if payload is None else len(payload)
+            self.slots[rank] = pack_bytes_array(payload, n + 5)
+            self.barrier.wait()
+            got = next((unpack_bytes_array(self.slots[src])
+                        for src, dst in perm if dst == rank), None)
+            self.barrier.wait()
+            return got
+        return exchange
+
+
+@pytest.mark.parametrize("size", [2, 3, 5])
+def test_jaxcomm_finalize_over_byte_transport_matches_threadcomm(size):
+    """A tree finalize over JaxComm -- reduce_tree of serialized states and
+    gather_tree of timestamp bytes, both on a bytes-only transport --
+    writes the same trace files as ThreadComm."""
+    from repro.core.comm import JaxComm
+
+    rng = random.Random(size)
+    calls = [_gen_calls(rng, 40, r, size) for r in range(size)]
+    root = tempfile.mkdtemp()
+    xport = _BytesOnlyTransport(size)
+    try:
+        def run(comm, r, tdir):
+            rec = Recorder(rank=r, config=RecorderConfig(trace_dir=tdir))
+            _feed(rec, calls[r])
+            rec.finalize(comm)
+
+        ref = os.path.join(root, "ref")
+        run_thread_world(size, lambda c, r: run(c, r, ref))
+
+        def jax_rank(comm, r):
+            jc = JaxComm(transport=type("X", (), {
+                "exchange": staticmethod(xport.exchange_for(r))})())
+            jc.rank, jc.size = r, size
+            jc.barrier = comm.barrier
+            run(jc, r, os.path.join(root, "jax"))
+
+        run_thread_world(size, jax_rank)
+        snap = {k: v for k, v in _dir_snapshot(os.path.join(root, "jax"))
+                .items() if k.endswith(".bin")}
+        want = {k: v for k, v in _dir_snapshot(ref).items()
+                if k.endswith(".bin")}
+        assert snap and snap == want
+    finally:
+        shutil.rmtree(root, ignore_errors=True)
+
+
 def test_vote_any_threadcomm():
     def worker(comm, rank):
         return comm.vote_any(rank == 2), comm.vote_any(False)

@@ -118,7 +118,6 @@ def test_compressed_crosspod_psum():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.distributed.sharding import shard_map
         from repro.optim.compress import compressed_psum_tree
         mesh = jax.make_mesh((2, 4), ("pod", "data"))
         g = {"w": jnp.arange(8.0).reshape(8, 1) * 1e-4}
@@ -127,7 +126,7 @@ def test_compressed_crosspod_psum():
         def f(g, e):
             return compressed_psum_tree(g, e, "pod")
 
-        out, err2 = jax.jit(shard_map(
+        out, err2 = jax.jit(jax.shard_map(
             f, mesh=mesh,
             in_specs=(P("pod", None), P("pod", None)),
             out_specs=(P("pod", None), P("pod", None))))(g["w"], err["w"])

@@ -201,6 +201,16 @@ def test_batch_fit_columns_backends_identical(n_cols, n_ranks):
         assert batch_fit_columns(cols, backend=b) == ref, b
 
 
+def test_fit_classify_values_whose_int32_deltas_wrap():
+    big = (1 << 31) - 1
+    V = np.array([[-big, big], [0, 1 << 30], [5, 5], [1 << 30, -(1 << 30)]],
+                 np.int64)
+    want = eb.fit_classify(V, "numpy")
+    got = eb.fit_classify(V, "pallas")
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(g, w)
+
+
 @pytest.mark.parametrize("k", [1, 2, 3])
 def test_arith_segments_backends_identical(k):
     V = np.concatenate([
@@ -243,7 +253,8 @@ def test_run_boundaries_backends_identical(n, k):
                                       err_msg=b)
 
 
-@pytest.mark.parametrize("n,n_bins", [(1, 4), (1000, 7), (5000, 64)])
+@pytest.mark.parametrize("n,n_bins", [(1, 4), (1000, 7), (5000, 64),
+                                      (9000, 200)])   # several bin tiles
 def test_terminal_histogram_backends_identical(n, n_bins):
     stream = rng.randint(0, n_bins, size=n).astype(np.int64)
     ref = eb.terminal_histogram(stream, n_bins, "python")
