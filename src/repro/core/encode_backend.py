@@ -37,6 +37,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import selftrace
 from .encoding import VarintRangeError, write_uvarint
 
 BACKENDS = ("auto", "python", "numpy", "pallas")
@@ -106,6 +107,17 @@ def resolve(backend: Optional[str], n: int) -> str:
     return "numpy"
 
 
+def _op(op: str, backend: str, n: int):
+    """Span ``encode.<op>`` around one dispatched call; with selftrace on
+    it also counts the call and its ``n`` elements under the backend that
+    runs it (``encode.<op>.<backend>.calls`` / ``.elements``), which says
+    what ``auto`` chose."""
+    if selftrace.on:
+        selftrace.count(f"encode.{op}.{backend}.calls")
+        selftrace.count(f"encode.{op}.{backend}.elements", n)
+    return selftrace.span(f"encode.{op}")
+
+
 # ---------------------------------------------------------------------------
 # delta + zigzag (timestamp pipeline stage)
 # ---------------------------------------------------------------------------
@@ -149,11 +161,12 @@ def delta_zigzag(flat: np.ndarray, backend: Optional[str] = None
     if flat.size == 0:
         return np.empty((0,), np.uint32)
     eff = resolve(backend, flat.size)
-    if eff == "python":
-        return _delta_zigzag_py(flat)
-    if eff == "pallas":
-        return _delta_zigzag_pallas(flat)
-    return _delta_zigzag_np(flat)
+    with _op("delta_zigzag", eff, flat.size):
+        if eff == "python":
+            return _delta_zigzag_py(flat)
+        if eff == "pallas":
+            return _delta_zigzag_pallas(flat)
+        return _delta_zigzag_np(flat)
 
 
 # ---------------------------------------------------------------------------
@@ -258,18 +271,20 @@ def encode_ticks_varint(ticks: np.ndarray, backend: Optional[str] = None
     if flat.size == 0:
         return b""
     eff = resolve(backend, flat.size)
-    if eff == "python":
-        return _encode_ticks_varint_py(flat)
-    if eff == "pallas":
-        import jax.numpy as jnp
-        from ..kernels.delta_encode.ops import delta_zigzag_varint
-        _zz, lens, planes = delta_zigzag_varint(
-            jnp.asarray(flat.astype(np.uint32)), interpret=interpret_mode())
-        return _emit_varint_bytes(np.asarray(lens, np.int64),
-                                  np.asarray(planes))
-    zz = _delta_zigzag_np(flat).astype(np.uint64)
-    lens, planes = _uvarint_planes_np(zz)
-    return _emit_varint_bytes(lens, planes[:5])
+    with _op("encode_ticks_varint", eff, flat.size):
+        if eff == "python":
+            return _encode_ticks_varint_py(flat)
+        if eff == "pallas":
+            import jax.numpy as jnp
+            from ..kernels.delta_encode.ops import delta_zigzag_varint
+            _zz, lens, planes = delta_zigzag_varint(
+                jnp.asarray(flat.astype(np.uint32)),
+                interpret=interpret_mode())
+            return _emit_varint_bytes(np.asarray(lens, np.int64),
+                                      np.asarray(planes))
+        zz = _delta_zigzag_np(flat).astype(np.uint64)
+        lens, planes = _uvarint_planes_np(zz)
+        return _emit_varint_bytes(lens, planes[:5])
 
 
 # ---------------------------------------------------------------------------
@@ -299,19 +314,22 @@ def run_boundaries(V: np.ndarray, backend: Optional[str] = None
     if n == 0:
         return np.zeros(0, bool)
     eff = resolve(backend, V.size)
-    if eff == "python":
-        return _run_boundaries_py(V)
-    if eff == "pallas" and np.abs(V).max(initial=0) < _I32_SAFE:
-        import jax.numpy as jnp
-        from ..kernels.grammar_stats.ops import row_boundaries
-        out = row_boundaries(jnp.asarray(V.astype(np.int32)),
-                             interpret=interpret_mode())
-        return np.asarray(out).astype(bool)
-    mask = np.empty(n, bool)
-    mask[0] = True
-    if n > 1:
-        mask[1:] = (V[1:] != V[:-1]).any(axis=1)
-    return mask
+    if eff == "pallas" and np.abs(V).max(initial=0) >= _I32_SAFE:
+        eff = "numpy"
+    with _op("run_boundaries", eff, V.size):
+        if eff == "python":
+            return _run_boundaries_py(V)
+        if eff == "pallas":
+            import jax.numpy as jnp
+            from ..kernels.grammar_stats.ops import row_boundaries
+            out = row_boundaries(jnp.asarray(V.astype(np.int32)),
+                                 interpret=interpret_mode())
+            return np.asarray(out).astype(bool)
+        mask = np.empty(n, bool)
+        mask[0] = True
+        if n > 1:
+            mask[1:] = (V[1:] != V[:-1]).any(axis=1)
+        return mask
 
 
 # ---------------------------------------------------------------------------
@@ -325,17 +343,20 @@ def fit_classify(V: np.ndarray, backend: Optional[str] = None
     value matrix with R >= 2 -- the vectorized core of the rank-linear
     fitter.  The pallas path runs one kernel call over column tiles and
     falls back to NumPy when the kernel's int32 differences could wrap."""
-    if backend == "pallas" and np.abs(V).max(initial=0) < _I32_DIFF_SAFE:
-        import jax.numpy as jnp
-        from ..kernels.delta_encode.ops import fit_columns
-        flags, d0 = fit_columns(jnp.asarray(V.astype(np.int32)),
-                                interpret=interpret_mode())
-        flags = np.asarray(flags)
-        return flags == 1, flags == 2, np.asarray(d0).astype(np.int64)
-    d = V[:, 1:] - V[:, :-1]
-    const = (d == 0).all(axis=1)
-    linear = (d == d[:, :1]).all(axis=1) & (d[:, 0] != 0)
-    return const, linear, d[:, 0]
+    eff = ("pallas" if backend == "pallas"
+           and np.abs(V).max(initial=0) < _I32_DIFF_SAFE else "numpy")
+    with _op("fit_classify", eff, V.size):
+        if eff == "pallas":
+            import jax.numpy as jnp
+            from ..kernels.delta_encode.ops import fit_columns
+            flags, d0 = fit_columns(jnp.asarray(V.astype(np.int32)),
+                                    interpret=interpret_mode())
+            flags = np.asarray(flags)
+            return flags == 1, flags == 2, np.asarray(d0).astype(np.int64)
+        d = V[:, 1:] - V[:, :-1]
+        const = (d == 0).all(axis=1)
+        linear = (d == d[:, :1]).all(axis=1) & (d[:, 0] != 0)
+        return const, linear, d[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -351,20 +372,23 @@ def terminal_histogram(stream: np.ndarray, n_bins: int,
     if stream.size == 0:
         return np.zeros(n_bins, np.int64)
     eff = resolve(backend, stream.size)
-    if eff == "pallas" and stream.max(initial=0) < _I32_SAFE:
-        import jax.numpy as jnp
-        from ..kernels.grammar_stats.ops import histogram
-        out = histogram(jnp.asarray(stream.astype(np.int32)), n_bins,
-                        interpret=interpret_mode())
-        return np.asarray(out).astype(np.int64)
-    if eff == "python":
-        out = np.zeros(n_bins, np.int64)
-        for t in stream.tolist():
-            if 0 <= t < n_bins:
-                out[t] += 1
-        return out
-    return np.bincount(stream[(stream >= 0) & (stream < n_bins)],
-                       minlength=n_bins)[:n_bins].astype(np.int64)
+    if eff == "pallas" and stream.max(initial=0) >= _I32_SAFE:
+        eff = "numpy"
+    with _op("terminal_histogram", eff, stream.size):
+        if eff == "pallas":
+            import jax.numpy as jnp
+            from ..kernels.grammar_stats.ops import histogram
+            out = histogram(jnp.asarray(stream.astype(np.int32)), n_bins,
+                            interpret=interpret_mode())
+            return np.asarray(out).astype(np.int64)
+        if eff == "python":
+            out = np.zeros(n_bins, np.int64)
+            for t in stream.tolist():
+                if 0 <= t < n_bins:
+                    out[t] += 1
+            return out
+        return np.bincount(stream[(stream >= 0) & (stream < n_bins)],
+                           minlength=n_bins)[:n_bins].astype(np.int64)
 
 
 def digram_histogram(stream: np.ndarray, n_terminals: int,
@@ -379,26 +403,28 @@ def digram_histogram(stream: np.ndarray, n_terminals: int,
     if stream.size < 2:
         return {}
     eff = resolve(backend, stream.size)
-    if (eff == "pallas"
-            and n_terminals * (n_terminals + 1) < _I32_SAFE):
-        import jax.numpy as jnp
-        from ..kernels.grammar_stats.ops import digram_codes
-        codes = np.asarray(digram_codes(
-            jnp.asarray(stream.astype(np.int32)), n_terminals,
-            interpret=interpret_mode())).astype(np.int64)
-        codes = codes[codes >= 0]
-    elif eff == "python":
-        counts: Dict[Tuple[int, int], int] = {}
-        prev = None
-        for t in stream.tolist():
-            if prev is not None:
-                k = (prev, t)
-                counts[k] = counts.get(k, 0) + 1
-            prev = t
-        return counts
-    else:
-        codes = stream[:-1] * n_terminals + stream[1:]
-    hist = np.bincount(codes)
-    nz = np.flatnonzero(hist)
-    return {(int(c) // n_terminals, int(c) % n_terminals): int(hist[c])
-            for c in nz}
+    if eff == "pallas" and n_terminals * (n_terminals + 1) >= _I32_SAFE:
+        eff = "numpy"
+    with _op("digram_histogram", eff, stream.size):
+        if eff == "pallas":
+            import jax.numpy as jnp
+            from ..kernels.grammar_stats.ops import digram_codes
+            codes = np.asarray(digram_codes(
+                jnp.asarray(stream.astype(np.int32)), n_terminals,
+                interpret=interpret_mode())).astype(np.int64)
+            codes = codes[codes >= 0]
+        elif eff == "python":
+            counts: Dict[Tuple[int, int], int] = {}
+            prev = None
+            for t in stream.tolist():
+                if prev is not None:
+                    k = (prev, t)
+                    counts[k] = counts.get(k, 0) + 1
+                prev = t
+            return counts
+        else:
+            codes = stream[:-1] * n_terminals + stream[1:]
+        hist = np.bincount(codes)
+        nz = np.flatnonzero(hist)
+        return {(int(c) // n_terminals, int(c) % n_terminals): int(hist[c])
+                for c in nz}

@@ -42,7 +42,7 @@ from .patterns import IntraPatternTracker
 from .sequitur import Sequitur, concat_grammars
 from .specs import DATA_FUNCS, REGISTRY, FunctionRegistry, Role
 from .timestamps import TimestampBuffer, compress_timestamps
-from . import streaming, trace_format
+from . import selftrace, streaming, trace_format
 
 
 def _env_int(name: str, minimum: int = 1) -> Optional[int]:
@@ -70,6 +70,12 @@ def _env_float(name: str, minimum: float = 0.0) -> Optional[float]:
     if not v > minimum:
         raise ValueError(f"{name} must be > {minimum}, got {v}")
     return v
+
+
+# selftrace counters of the record path, in Recorder._stage_sums's order
+_STAGE_COUNTERS = ("record.calls", "record.total_ns", "record.patterns_ns",
+                   "record.signature_ns", "record.grammar_ns",
+                   "record.wrapped_us")
 
 
 @dataclass
@@ -243,6 +249,11 @@ class Recorder:
         self.n_records = 0
         self.n_skipped = 0
         self._finalized = False
+        # with selftrace on: record() calls, then ns in record() whole, in
+        # its patterns, signature and grammar stages, then the traced
+        # calls' own ticks (us); kept under _lock, folded into selftrace's
+        # counters at each flush and finalize
+        self._stage_sums = [0] * len(_STAGE_COUNTERS)
         # -- streaming state (core/streaming.py) --------------------------------
         self._comm = comm                 # default comm for flush/finalize
         self.epoch = 0                    # committed flushes so far
@@ -316,6 +327,7 @@ class Recorder:
 
     def record(self, func_id: int, raw_args: tuple, ret: Any, depth: int,
                t0: int, t1: int) -> None:
+        c0 = time.perf_counter_ns() if selftrace.on else 0
         spec = self.registry.spec(func_id)
         with self._lock:
             self._record_locked(spec, func_id, raw_args, ret, depth, t0, t1)
@@ -323,9 +335,28 @@ class Recorder:
             # auto-flush only from top-level calls (a flush inside a layered
             # call would split parent and child records across epochs)
             self._maybe_autoflush()
+        if c0:
+            dt = time.perf_counter_ns() - c0
+            with self._lock:
+                sums = self._stage_sums
+                sums[0] += 1
+                sums[1] += dt
+                sums[5] += t1 - t0
+
+    def _fold_stage_sums_locked(self) -> None:
+        """Add the record path's stage sums to selftrace's counters and
+        restart them (callers hold ``self._lock``)."""
+        sums = self._stage_sums
+        if sums[0]:
+            for name, v in zip(_STAGE_COUNTERS, sums):
+                selftrace.count(name, v)
+            self._stage_sums = [0] * len(_STAGE_COUNTERS)
 
     def _record_locked(self, spec, func_id: int, raw_args: tuple, ret: Any,
                        depth: int, t0: int, t1: int) -> None:
+        traced = selftrace.on
+        if traced:
+            c1 = time.perf_counter_ns()
         tidx = self._thread_index()
         norm: List[Any] = []
         offsets: List[int] = []
@@ -406,9 +437,18 @@ class Recorder:
             if ret_is_offset:
                 nret = encoded[-1]
 
+        if traced:
+            c2 = time.perf_counter_ns()
         sig = trace_format.make_signature(func_id, tidx, depth, tuple(norm), nret)
         terminal = self.cst.intern(sig)
+        if traced:
+            c3 = time.perf_counter_ns()
         self.grammar.push(terminal)
+        if traced:
+            sums = self._stage_sums
+            sums[2] += c2 - c1
+            sums[3] += c3 - c2
+            sums[4] += time.perf_counter_ns() - c3
         if self.config.timestamps:
             if self._epoch_first_tick is None:
                 self._epoch_first_tick = t0
@@ -465,6 +505,7 @@ class Recorder:
         same layout segment stitching produces), so retried records land
         in the next committed epoch exactly once."""
         with self._lock:
+            self._fold_stage_sums_locked()
             entries = self.cst.entries
             cfg = self.grammar.serialize()
             ticks = self.timestamps.take()
@@ -565,12 +606,22 @@ class Recorder:
 
     def _flush_locked(self, comm: Comm, trace_dir: str
                       ) -> Optional[Dict[str, Any]]:
-        entries, cfg, ticks, wraps = self.take_epoch()
-        epoch = self.epoch
-        self.epoch += 1
-        self._last_flush_t = time.perf_counter()
-        return self._commit_epoch(comm, trace_dir, entries, cfg, ticks,
-                                  wraps, epoch)
+        with selftrace.span("flush"):
+            entries, cfg, ticks, wraps = self._take_epoch_traced()
+            epoch = self.epoch
+            self.epoch += 1
+            self._last_flush_t = time.perf_counter()
+            return self._commit_epoch(comm, trace_dir, entries, cfg, ticks,
+                                      wraps, epoch)
+
+    def _take_epoch_traced(self) -> Tuple[List[bytes], bytes, Any, int]:
+        """``take_epoch`` as span ``take_epoch``, counting the epoch's
+        records (``flush.records``)."""
+        with selftrace.span("take_epoch"):
+            epoch = self.take_epoch()
+        selftrace.count("flush.records",
+                        self._records_at_flush - self._records_at_flush_prev)
+        return epoch
 
     def _flush_async_locked(self, comm: Comm, trace_dir: str) -> None:
         self._reap()
@@ -583,7 +634,8 @@ class Recorder:
         if busy:
             self.epochs_coalesced += 1
             return None
-        entries, cfg, ticks, wraps = self.take_epoch()
+        with selftrace.span("flush"):
+            entries, cfg, ticks, wraps = self._take_epoch_traced()
         epoch = self.epoch
         self.epoch += 1
         self._last_flush_t = time.perf_counter()
@@ -808,8 +860,9 @@ class Recorder:
 
     def local_state(self) -> Tuple[List[bytes], bytes, bytes]:
         """(CST entries, serialized CFG, compressed timestamps)."""
-        ts = compress_timestamps(self.timestamps.as_array(),
-                                 backend=self.config.encode_backend)
+        with selftrace.span("encode"):
+            ts = compress_timestamps(self.timestamps.as_array(),
+                                     backend=self.config.encode_backend)
         return self.cst.entries, self.grammar.serialize(), ts
 
     def finalize(self, comm: Optional[Comm] = None,
@@ -831,47 +884,64 @@ class Recorder:
         last epoch segment and rank 0 materializes the cumulative
         cross-epoch state into ``<trace_dir>/merged`` -- the incremental
         finalize: no re-reduction of earlier epochs ever happens.
+
+        With selftrace on, the call is span ``finalize``; a one-shot
+        finalize's children are ``encode``, ``reduce``, ``materialize``
+        and ``write``, a streaming one's the tail ``flush`` and the merged
+        trace's ``write``.
         """
         if self._finalized:
             raise RuntimeError("recorder already finalized")
         comm = comm or self._comm or SoloComm()
         trace_dir = trace_dir or self.config.trace_dir
-        if self._is_streaming():
-            if not trace_dir:
-                raise ValueError("streaming finalize requires a trace_dir")
-            # drain any in-flight background commit FIRST (its failure must
-            # surface here, not vanish), then flush the tail synchronously;
-            # the tail flush is skippable only when provably empty AND the
-            # decision needs no agreement (solo comm) -- multi-rank flushes
-            # are collective, so every rank must make the same call.  The
-            # _finalized flip happens under the flush lock so a racing
-            # auto-flush can never commit an epoch after the tail (it
-            # re-checks the flag under the same lock).  Safe to wait on the
-            # future while holding the lock: the background commit never
-            # takes it.
-            with self._flush_lock:
-                self._drain_locked()
-                self._maybe_resume(comm, trace_dir)
-                if (comm.size > 1 or self.epoch == 0
-                        or self.n_records > self._records_at_flush):
-                    self._flush_locked(comm, trace_dir)
-                self._finalized = True
-            if self._flush_pool is not None:
-                self._flush_pool.shutdown(wait=True)
-                self._flush_pool = None
-            if comm.rank != 0:
-                self._finalize_sync(comm)
-                return None
-            if self.config.max_epochs_retained is None:
+        with self._lock:
+            self._fold_stage_sums_locked()
+        with selftrace.span("finalize"):
+            if self._is_streaming():
+                return self._finalize_streaming(comm, trace_dir)
+            return self._finalize_oneshot(comm, trace_dir)
+
+    def _finalize_streaming(self, comm: Comm, trace_dir: Optional[str]
+                            ) -> Optional[RecorderStats]:
+        if not trace_dir:
+            raise ValueError("streaming finalize requires a trace_dir")
+        # drain any in-flight background commit FIRST (its failure must
+        # surface here, not vanish), then flush the tail synchronously;
+        # the tail flush is skippable only when provably empty AND the
+        # decision needs no agreement (solo comm) -- multi-rank flushes
+        # are collective, so every rank must make the same call.  The
+        # _finalized flip happens under the flush lock so a racing
+        # auto-flush can never commit an epoch after the tail (it
+        # re-checks the flag under the same lock).  Safe to wait on the
+        # future while holding the lock: the background commit never
+        # takes it.
+        with self._flush_lock:
+            self._drain_locked()
+            self._maybe_resume(comm, trace_dir)
+            if (comm.size > 1 or self.epoch == 0
+                    or self.n_records > self._records_at_flush):
+                self._flush_locked(comm, trace_dir)
+            self._finalized = True
+        if self._flush_pool is not None:
+            self._flush_pool.shutdown(wait=True)
+            self._flush_pool = None
+        if comm.rank != 0:
+            self._finalize_sync(comm)
+            return None
+        if self.config.max_epochs_retained is None:
+            with selftrace.span("write"):
                 streaming.write_merged_trace(
                     trace_dir, self._cum, registry=self.registry,
                     inter_patterns=self.config.inter_patterns,
                     meta_extra=self._metadata(comm.size))
-            stats = self._stream_totals
-            stats.n_records = self.n_records
-            stats.n_skipped = self.n_skipped
-            self._finalize_sync(comm)
-            return stats
+        stats = self._stream_totals
+        stats.n_records = self.n_records
+        stats.n_skipped = self.n_skipped
+        self._finalize_sync(comm)
+        return stats
+
+    def _finalize_oneshot(self, comm: Comm, trace_dir: Optional[str]
+                          ) -> Optional[RecorderStats]:
         self._finalized = True
         if self.config.finalize_topology not in ("tree", "flat"):
             raise ValueError(
@@ -879,30 +949,34 @@ class Recorder:
                 f"{self.config.finalize_topology!r}")
         entries, cfg, ts = self.local_state()
         if self.config.finalize_topology == "tree":
-            leaf = make_rank_state(comm.rank, entries, cfg, self.registry)
-            blob = comm.reduce_tree(serialize_rank_state(leaf),
-                                    merge_serialized_states)
-            ts_gathered = comm.gather_tree(ts)
+            with selftrace.span("reduce"):
+                leaf = make_rank_state(comm.rank, entries, cfg, self.registry)
+                blob = comm.reduce_tree(serialize_rank_state(leaf),
+                                        merge_serialized_states)
+                rank_ts = comm.gather_tree(ts)
             if comm.rank != 0:
                 comm.barrier()
                 return None
-            rank_ts = ts_gathered
-            merge, cfgs = materialize_state(
-                deserialize_rank_state(blob),
-                inter_patterns=self.config.inter_patterns)
+            with selftrace.span("materialize"):
+                merge, cfgs = materialize_state(
+                    deserialize_rank_state(blob),
+                    inter_patterns=self.config.inter_patterns)
         else:
-            gathered = comm.gather((entries, cfg, ts))
+            with selftrace.span("reduce"):
+                gathered = comm.gather((entries, cfg, ts))
             if comm.rank != 0:
                 comm.barrier()
                 return None
             rank_csts = [g[0] for g in gathered]
             rank_cfgs = [g[1] for g in gathered]
             rank_ts = [g[2] for g in gathered]
-            merge, cfgs = finalize_ranks(
-                rank_csts, rank_cfgs, self.registry,
-                inter_patterns=self.config.inter_patterns,
-                fit_mode=("pallas" if self.config.encode_backend == "pallas"
-                          else "vectorized"))
+            with selftrace.span("materialize"):
+                merge, cfgs = finalize_ranks(
+                    rank_csts, rank_cfgs, self.registry,
+                    inter_patterns=self.config.inter_patterns,
+                    fit_mode=("pallas"
+                              if self.config.encode_backend == "pallas"
+                              else "vectorized"))
         stats = RecorderStats(
             n_records=self.n_records,
             n_skipped=self.n_skipped,
@@ -912,15 +986,16 @@ class Recorder:
             ts_bytes=sum(len(t) for t in rank_ts),
         )
         if trace_dir:
-            trace_format.write_trace(
-                trace_dir,
-                registry=self.registry,
-                merged_cst=merge.merged_entries,
-                unique_cfgs=cfgs.unique_cfgs,
-                cfg_index=cfgs.cfg_index,
-                rank_timestamps=rank_ts,
-                meta_extra=self._metadata(comm.size),
-            )
+            with selftrace.span("write"):
+                trace_format.write_trace(
+                    trace_dir,
+                    registry=self.registry,
+                    merged_cst=merge.merged_entries,
+                    unique_cfgs=cfgs.unique_cfgs,
+                    cfg_index=cfgs.cfg_index,
+                    rank_timestamps=rank_ts,
+                    meta_extra=self._metadata(comm.size),
+                )
         comm.barrier()
         return stats
 

@@ -1,3 +1,3 @@
-from .loop import StragglerDetector, Trainer, TrainerConfig
+from .loop import Trainer, TrainerConfig
 
-__all__ = ["Trainer", "TrainerConfig", "StragglerDetector"]
+__all__ = ["Trainer", "TrainerConfig"]

@@ -8,18 +8,13 @@ Production behaviors, all exercised by tests:
     the traced I/O facades -- a Recorder session sees the whole step loop
     (``frame.step`` events) plus the checkpoint call chains,
   * step retry with restore-on-repeated-failure,
-  * straggler detection: per-step wall-time z-score against a running
-    mean/variance; slow steps are reported (on a real pod this feeds the
-    controller's slow-host list),
   * gradient-accumulation microbatching (``accum_steps``) for memory,
   * deterministic, resumable data (state == step counter).
 """
 
 from __future__ import annotations
 
-import math
-import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional
 
 import jax
@@ -41,35 +36,9 @@ class TrainerConfig:
     keep: int = 2
     log_every: int = 10
     retry_max: int = 2
-    straggler_z: float = 3.0
     async_ckpt: bool = False
     accum_steps: int = 1
     seed: int = 0
-
-
-class StragglerDetector:
-    """Welford running mean/var over step times; flags z-score outliers."""
-
-    def __init__(self, z: float = 3.0, warmup: int = 8):
-        self.z = z
-        self.warmup = warmup
-        self.n = 0
-        self.mean = 0.0
-        self.m2 = 0.0
-        self.flagged: List[int] = []
-
-    def update(self, step: int, dt: float) -> bool:
-        slow = False
-        if self.n >= self.warmup:
-            std = math.sqrt(self.m2 / max(self.n - 1, 1))
-            if std > 0 and (dt - self.mean) / std > self.z:
-                slow = True
-                self.flagged.append(step)
-        self.n += 1
-        d = dt - self.mean
-        self.mean += d / self.n
-        self.m2 += d * (dt - self.mean)
-        return slow
 
 
 class Trainer:
@@ -85,7 +54,6 @@ class Trainer:
         self.fault_hook = fault_hook
         self.engine = CheckpointEngine(tcfg.ckpt_dir, keep=tcfg.keep,
                                        async_save=tcfg.async_ckpt)
-        self.straggler = StragglerDetector(z=tcfg.straggler_z)
         self._step_fn = jax.jit(
             make_train_step(cfg, self.ocfg, accum_steps=tcfg.accum_steps),
             donate_argnums=(0,))
@@ -132,7 +100,6 @@ class Trainer:
         retries = 0
         while step < self.tcfg.num_steps:
             frame.step(step)
-            t0 = time.perf_counter()
             try:
                 metrics = self._run_step(step)
             except Exception:
@@ -151,9 +118,6 @@ class Trainer:
                 retries = 0
                 continue
             retries = 0
-            dt = time.perf_counter() - t0
-            self.straggler.update(step, dt)
-            metrics["step_time_s"] = dt
             metrics["step"] = step
             self.metrics_log.append(metrics)
             step += 1
@@ -165,6 +129,5 @@ class Trainer:
                 frame.ckpt_end(step, nbytes)
         self.engine.wait()
         return {"final_step": step,
-                "stragglers": list(self.straggler.flagged),
                 "last_loss": self.metrics_log[-1]["loss"]
                 if self.metrics_log else float("nan")}

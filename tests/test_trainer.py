@@ -13,7 +13,7 @@ from repro.configs import get_smoke_config
 from repro.data import SyntheticConfig, synthetic_batch
 from repro.optim import (AdamWConfig, adamw_init, adamw_update, cosine_lr,
                          ef_int8_compress, ef_int8_decompress)
-from repro.train import StragglerDetector, Trainer, TrainerConfig
+from repro.train import Trainer, TrainerConfig
 
 CFG = get_smoke_config("qwen1.5-0.5b").replace(loss_chunk=0)
 # seeds pinned explicitly: batches are deterministic in (seed, step) and
@@ -72,15 +72,6 @@ def test_step_retry_and_restore_on_fault(tmp_path):
     res = tr.run()
     assert res["final_step"] == 9
     assert calls["n"] >= 3            # retried, restored, retried again
-
-
-def test_straggler_detector():
-    det = StragglerDetector(z=3.0, warmup=5)
-    for i in range(20):
-        det.update(i, 0.1 + (0.001 * (i % 3)))
-    assert det.update(20, 5.0) is True
-    assert 20 in det.flagged
-    assert det.update(21, 0.1) is False
 
 
 def test_async_checkpoint(tmp_path):
