@@ -22,6 +22,13 @@ whole sequence of calls for one key at once, finding arithmetic runs with
 the shared NumPy segmentation helper (``interprocess.arith_segments``) and
 is result- and state-equivalent to calling :meth:`encode` per call.  The
 benchmark drivers use it to synthesize large simulated-rank streams.
+
+``IntraPatternTracker.advance`` is the record path's entry point.  Every
+call that continues a run has one signature, so a run keeps the CST
+terminal of that signature (and the call depth it was made at) once the
+record path has interned it; a later call that continues the run at that
+depth only advances the run and reuses the terminal.  A broken run is a
+new ``_RunState``, so the terminal cannot outlive its run.
 """
 
 from __future__ import annotations
@@ -40,6 +47,10 @@ class _RunState:
     index: int                      # how many calls matched this run so far
     base: Tuple[int, ...]           # offsets of call 0
     stride: Optional[Tuple[int, ...]]  # set at call 1
+    # the record path's memo: the CST terminal of the run's IterPattern
+    # signature, interned at call depth ``depth``
+    terminal: Optional[int] = None
+    depth: Optional[int] = None
 
 
 Encoded = Union[int, IterPattern]
@@ -58,7 +69,39 @@ class IntraPatternTracker:
         vals = tuple(int(v) for v in offsets)
         if not self.enabled or not vals:
             return list(vals)
+        return self._encode(key, self._runs.get(key), vals)
+
+    def advance(self, key: Any, offsets: List[int], depth: Optional[int]
+                ) -> Tuple[Optional[_RunState], Optional[List[Encoded]]]:
+        """:meth:`encode` for the record path: one call's OFFSET-role
+        values (a list of ints) at call depth ``depth``.
+
+        ``(run, None)``: the values continue ``key``'s run, whose terminal
+        was kept at ``depth``; the run advanced as :meth:`encode` would
+        advance it, and nothing was built.  Otherwise ``(run, encoded)``
+        with ``encoded`` what :meth:`encode` returns, and ``run`` the run
+        its ``IterPattern`` values belong to, whose ``terminal`` and
+        ``depth`` the caller sets once it has interned the signature; it
+        is None where the values are concrete, and where ``depth`` is None
+        (a call whose signature the caller cannot keep)."""
         run = self._runs.get(key)
+        if run is not None and run.terminal is not None \
+                and run.depth == depth:
+            i = run.index
+            if offsets == [b + i * a for a, b in zip(run.stride, run.base)]:
+                run.index = i + 1
+                return run, None
+        vals = tuple(int(v) for v in offsets)
+        if not self.enabled or not vals:
+            return None, list(vals)
+        encoded = self._encode(key, run, vals)
+        if depth is None or not isinstance(encoded[0], IterPattern):
+            return None, encoded
+        return run, encoded
+
+    def _encode(self, key: Any, run: Optional[_RunState],
+                vals: Tuple[int, ...]) -> List[Encoded]:
+        """:meth:`encode` given ``key``'s run as looked up."""
         if run is None:
             self._runs[key] = _RunState(index=1, base=vals, stride=None)
             return list(vals)

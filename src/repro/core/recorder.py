@@ -75,7 +75,13 @@ def _env_float(name: str, minimum: float = 0.0) -> Optional[float]:
 # selftrace counters of the record path, in Recorder._stage_sums's order
 _STAGE_COUNTERS = ("record.calls", "record.total_ns", "record.patterns_ns",
                    "record.signature_ns", "record.grammar_ns",
-                   "record.wrapped_us")
+                   "record.wrapped_us", "record.memo_hits")
+
+# Types whose equal values have equal signature encodings.  A run keeps
+# its signature's terminal only for calls whose SIZE/VAL arguments and
+# return value are of these types: the pattern key compares values, and
+# True == 1 == 1.0 == numpy.int64(1) (and 0.0 == -0.0) encode apart.
+_KEPT_TYPES = frozenset({int, str, bytes, type(None), Handle})
 
 
 @dataclass
@@ -251,8 +257,9 @@ class Recorder:
         self._finalized = False
         # with selftrace on: record() calls, then ns in record() whole, in
         # its patterns, signature and grammar stages, then the traced
-        # calls' own ticks (us); kept under _lock, folded into selftrace's
-        # counters at each flush and finalize
+        # calls' own ticks (us), then the calls that reused their run's
+        # terminal; kept under _lock, folded into selftrace's counters at
+        # each flush and finalize
         self._stage_sums = [0] * len(_STAGE_COUNTERS)
         # -- streaming state (core/streaming.py) --------------------------------
         self._comm = comm                 # default comm for flush/finalize
@@ -363,6 +370,7 @@ class Recorder:
         offset_slots: List[int] = []
         handle_ids: List[int] = []
         keyparts: List[Any] = []
+        keepable = True     # see _KEPT_TYPES
         prefixes = self.config.path_prefixes
         for i, arg in enumerate(raw_args):
             role = spec.args[i].role if i < len(spec.args) else Role.VAL
@@ -399,6 +407,8 @@ class Recorder:
                 norm.append(v)
                 keyparts.append(v)
             else:  # SIZE / VAL
+                if type(arg) not in _KEPT_TYPES:
+                    keepable = False
                 norm.append(arg)
                 keyparts.append(arg)
 
@@ -427,22 +437,43 @@ class Recorder:
                          and isinstance(nret, int) and not is_err)
 
         # intra-process I/O pattern encoding (paper §3.2.1)
+        run = terminal = None
         if offsets or ret_is_offset:
             key = (func_id, tidx, tuple(handle_ids), tuple(keyparts),
                    None if ret_is_offset else key_ret)
-            vals = offsets + ([nret] if ret_is_offset else [])
-            encoded = self.intra.encode(key, vals)
-            for slot, val in zip(offset_slots, encoded):
-                norm[slot] = val
             if ret_is_offset:
-                nret = encoded[-1]
+                vals = offsets + [nret]
+            else:
+                vals = offsets
+                if type(nret) not in _KEPT_TYPES:
+                    keepable = False
+            run, encoded = self.intra.advance(
+                key, vals, depth if keepable else None)
+            if encoded is None:
+                # the call continues a run whose signature is interned:
+                # its terminal is the run's (the OFFSET-role return it
+                # would encode is an IterPattern: no data bytes)
+                terminal = run.terminal
+                if ret_is_offset:
+                    nret = None
+            else:
+                for slot, val in zip(offset_slots, encoded):
+                    norm[slot] = val
+                if ret_is_offset:
+                    nret = encoded[-1]
 
         if traced:
-            c2 = time.perf_counter_ns()
-        sig = trace_format.make_signature(func_id, tidx, depth, tuple(norm), nret)
-        terminal = self.cst.intern(sig)
-        if traced:
-            c3 = time.perf_counter_ns()
+            c2 = c3 = time.perf_counter_ns()
+        if terminal is None:
+            sig = trace_format.make_signature(func_id, tidx, depth,
+                                              tuple(norm), nret)
+            terminal = self.cst.intern(sig)
+            if run is not None:
+                run.terminal, run.depth = terminal, depth
+            if traced:
+                c3 = time.perf_counter_ns()
+        elif traced:
+            self._stage_sums[6] += 1
         self.grammar.push(terminal)
         if traced:
             sums = self._stage_sums

@@ -159,6 +159,45 @@ def test_traced_pwrite_loop_stage_sums(traced, tmp_path):
     assert rec._stage_sums == [0] * len(rec._stage_sums)
 
 
+@pytest.mark.parametrize("offsets,on", [("strided", True), ("random", True),
+                                        ("strided", False)])
+def test_memo_hits_counter(tmp_path, offsets, on):
+    """A strided loop reuses its run's terminal from its third call on;
+    random offsets never continue a run; with selftrace off nothing is
+    counted."""
+    n = 1024
+    rng = np.random.default_rng(15)
+    offs = (np.arange(n) * 4096 if offsets == "strided"
+            else rng.integers(0, 1 << 24, n)).tolist()
+    rec = Recorder(config=RecorderConfig(trace_dir=str(tmp_path / "t")))
+    fd = os.open(tmp_path / "data.bin", os.O_RDWR | os.O_CREAT, 0o644)
+    selftrace.reset()
+    if on:
+        selftrace.enable()
+    try:
+        attach(rec)
+        try:
+            for off in offs:
+                posix.pwrite(fd, b"\x5a" * 64, off)
+        finally:
+            detach()
+            os.close(fd)
+        assert rec._stage_sums[6] == (n - 2 if on and offsets == "strided"
+                                      else 0)
+        rec.finalize()
+        c = selftrace.snapshot()["counters"]
+    finally:
+        selftrace.disable()
+        selftrace.reset()
+    if not on:
+        assert c == {}
+    elif offsets == "strided":
+        assert c["record.memo_hits"] == c["record.calls"] - 2 == n - 2
+        assert c["record.signature_ns"] > 0      # the two calls that missed
+    else:
+        assert c["record.calls"] == n and c["record.memo_hits"] == 0
+
+
 def test_threadcomm_ranks_fold_their_own_sums(traced, tmp_path):
     """Ranks on threads, each with its recorder, fold into one set of
     counters: nothing is lost between them."""
