@@ -59,6 +59,8 @@ import struct
 import threading
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from . import selftrace
+
 #: (rank, payload length) header of one JaxComm.gather_tree frame
 _GATHER_FRAME = struct.Struct("<IQ")
 
@@ -607,4 +609,5 @@ class JaxComm(Comm):
         if self.size > 1:
             from jax.experimental import multihost_utils
 
-            multihost_utils.sync_global_devices("recorder_barrier")
+            with selftrace.span("barrier"):
+                multihost_utils.sync_global_devices("recorder_barrier")

@@ -16,13 +16,16 @@ Physical mapping (DESIGN.md Section 4):
 
 from __future__ import annotations
 
+import functools
 import threading
 from contextlib import contextmanager
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from ..core import selftrace
 
 _state = threading.local()
 
@@ -127,6 +130,7 @@ _LEN_HEADER = 5
 
 #: mesh axis the host transport permutes over (one device per process)
 HOST_AXIS = "hosts"
+_HOST_SPEC = P(HOST_AXIS, None)
 
 
 def pack_bytes_array(payload: Optional[bytes], pad_to: int) -> np.ndarray:
@@ -167,6 +171,16 @@ class PpermuteByteTransport:
     payload lengths to agree on a common array size, pack to uint8, lay
     the per-host arrays out over a 1-D ``hosts`` mesh (one device per
     process) and move them with a single shard_map'd ``lax.ppermute``.
+    That program is traced and built once per ``(perm, cap)`` and kept:
+    the schedule has ceil(log2 N) perms and the cap is a power of two, so
+    a job builds a handful and every later flush reuses them.
+
+    With selftrace on, an exchange is span ``exchange`` with children
+    ``exchange.lengths`` (the allgather) and ``exchange.ppermute`` (pack,
+    move, unpack), and counts ``exchange.calls``,
+    ``exchange.payload_bytes`` (this process's payload),
+    ``exchange.moved_bytes`` (the cap this process sent, 0 when it is no
+    sender) and ``exchange.builds`` (programs traced and built).
 
     Requires a multi-process jax runtime; with one process every schedule
     is empty, so ``exchange`` is never reached (``comm.JaxComm`` guards).
@@ -174,6 +188,7 @@ class PpermuteByteTransport:
 
     def __init__(self, mesh: Optional[Mesh] = None):
         self._mesh = mesh
+        self._built: Dict[tuple, Callable] = {}
 
     def _host_mesh(self) -> Mesh:
         if self._mesh is None:
@@ -182,40 +197,60 @@ class PpermuteByteTransport:
             self._mesh = Mesh(np.asarray(devs), (HOST_AXIS,))
         return self._mesh
 
+    def _program(self, perm: tuple, cap: int) -> Callable:
+        fn = self._built.get((perm, cap))
+        if fn is None:
+            fn = self._built[(perm, cap)] = jax.jit(jax.shard_map(
+                functools.partial(jax.lax.ppermute, axis_name=HOST_AXIS,
+                                  perm=perm),
+                mesh=self._host_mesh(), in_specs=_HOST_SPEC,
+                out_specs=_HOST_SPEC))
+            selftrace.count("exchange.builds")
+        return fn
+
     def exchange(self, payload: Optional[bytes],
                  perm: List[Tuple[int, int]]) -> Optional[bytes]:
         if not perm:
             return None
         from jax.experimental import multihost_utils
 
+        perm = tuple(map(tuple, perm))
         n = 0 if payload is None else len(payload)
-        cap = int(multihost_utils.process_allgather(
-            np.asarray([n], np.int64)).max()) + _LEN_HEADER
-        # a power of two, so a finalize compiles the exchange for a few
-        # array sizes rather than one per payload length
-        cap = 1 << max(6, (cap - 1).bit_length())
-        local = pack_bytes_array(payload, cap)[None, :]
-        mesh = self._host_mesh()
-        spec_ = P(HOST_AXIS, None)
-        global_arr = multihost_utils.host_local_array_to_global_array(
-            local, mesh, spec_)
-        shifted = jax.shard_map(
-            lambda x: jax.lax.ppermute(x, HOST_AXIS, perm),
-            mesh=mesh, in_specs=spec_, out_specs=spec_)(global_arr)
-        back = multihost_utils.global_array_to_host_local_array(
-            shifted, mesh, spec_)
-        return unpack_bytes_array(np.asarray(back)[0])
+        with selftrace.span("exchange"):
+            with selftrace.span("exchange.lengths"):
+                cap = int(multihost_utils.process_allgather(
+                    np.asarray([n], np.int64)).max()) + _LEN_HEADER
+            # a power of two, so a job builds the exchange for a few
+            # array sizes rather than one per payload length
+            cap = 1 << max(6, (cap - 1).bit_length())
+            with selftrace.span("exchange.ppermute"):
+                mesh = self._host_mesh()
+                global_arr = multihost_utils.host_local_array_to_global_array(
+                    pack_bytes_array(payload, cap)[None, :], mesh, _HOST_SPEC)
+                shifted = self._program(perm, cap)(global_arr)
+                back = multihost_utils.global_array_to_host_local_array(
+                    shifted, mesh, _HOST_SPEC)
+                got = unpack_bytes_array(np.asarray(back)[0])
+        if selftrace.on:
+            me = jax.process_index()
+            selftrace.count("exchange.calls")
+            selftrace.count("exchange.payload_bytes", n)
+            selftrace.count("exchange.moved_bytes",
+                            cap if any(s == me for s, _ in perm) else 0)
+        return got
 
 
 def global_any(flag: bool) -> bool:
     """Cross-process boolean OR (the flush-cadence vote): allgather one
-    uint8 per process and reduce locally.  Identity with one process."""
+    uint8 per process and reduce locally.  Identity with one process;
+    otherwise span ``vote`` with selftrace on."""
     if jax.process_count() == 1:
         return bool(flag)
     from jax.experimental import multihost_utils
 
-    votes = multihost_utils.process_allgather(
-        np.asarray([1 if flag else 0], np.uint8))
+    with selftrace.span("vote"):
+        votes = multihost_utils.process_allgather(
+            np.asarray([1 if flag else 0], np.uint8))
     return bool(np.asarray(votes).any())
 
 
