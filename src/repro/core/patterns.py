@@ -23,12 +23,13 @@ the shared NumPy segmentation helper (``interprocess.arith_segments``) and
 is result- and state-equivalent to calling :meth:`encode` per call.  The
 benchmark drivers use it to synthesize large simulated-rank streams.
 
-``IntraPatternTracker.advance`` is the record path's entry point.  Every
-call that continues a run has one signature, so a run keeps the CST
-terminal of that signature (and the call depth it was made at) once the
-record path has interned it; a later call that continues the run at that
-depth only advances the run and reuses the terminal.  A broken run is a
-new ``_RunState``, so the terminal cannot outlive its run.
+The record path keeps, in each run, the CST terminal of the signature
+that every call continuing the run shares (and the call depth it was
+interned at).  Its generated record code (``recorder.record_code``) looks
+the run up in ``IntraPatternTracker.runs`` and, on a call that continues
+it at that depth, only advances the run and reuses the terminal; any
+other call goes through ``IntraPatternTracker.encode_run``.  A broken
+run is a new ``_RunState``, so the terminal cannot outlive its run.
 """
 
 from __future__ import annotations
@@ -62,40 +63,32 @@ class IntraPatternTracker:
 
     def __init__(self, enabled: bool = True) -> None:
         self.enabled = enabled
-        self._runs: Dict[Any, _RunState] = {}
+        # pattern key -> its active run
+        self.runs: Dict[Any, _RunState] = {}
 
     def encode(self, key: Any, offsets: Sequence[int]) -> List[Encoded]:
         """Encode the OFFSET-role values of one call."""
         vals = tuple(int(v) for v in offsets)
         if not self.enabled or not vals:
             return list(vals)
-        return self._encode(key, self._runs.get(key), vals)
+        return self._encode(key, self.runs.get(key), vals)
 
-    def advance(self, key: Any, offsets: List[int], depth: Optional[int]
-                ) -> Tuple[Optional[_RunState], Optional[List[Encoded]]]:
-        """:meth:`encode` for the record path: one call's OFFSET-role
-        values (a list of ints) at call depth ``depth``.
+    def encode_run(self, key: Any, run: Optional[_RunState],
+                   offsets: List[int], keep: bool
+                   ) -> Tuple[Optional[_RunState], List[Encoded]]:
+        """:meth:`encode` for the record path, given ``key``'s run as it
+        looked it up (``runs.get(key)``): one call's OFFSET-role values
+        (ints), whose signature the caller keeps in the run if ``keep``.
 
-        ``(run, None)``: the values continue ``key``'s run, whose terminal
-        was kept at ``depth``; the run advanced as :meth:`encode` would
-        advance it, and nothing was built.  Otherwise ``(run, encoded)``
-        with ``encoded`` what :meth:`encode` returns, and ``run`` the run
-        its ``IterPattern`` values belong to, whose ``terminal`` and
-        ``depth`` the caller sets once it has interned the signature; it
-        is None where the values are concrete, and where ``depth`` is None
-        (a call whose signature the caller cannot keep)."""
-        run = self._runs.get(key)
-        if run is not None and run.terminal is not None \
-                and run.depth == depth:
-            i = run.index
-            if offsets == [b + i * a for a, b in zip(run.stride, run.base)]:
-                run.index = i + 1
-                return run, None
+        Returns what :meth:`encode` returns, after the run it belongs to:
+        the run whose ``terminal`` and ``depth`` the caller sets once it
+        has interned the signature, or None where the values are concrete
+        or ``keep`` is false."""
         vals = tuple(int(v) for v in offsets)
         if not self.enabled or not vals:
             return None, list(vals)
         encoded = self._encode(key, run, vals)
-        if depth is None or not isinstance(encoded[0], IterPattern):
+        if not keep or not isinstance(encoded[0], IterPattern):
             return None, encoded
         return run, encoded
 
@@ -103,7 +96,7 @@ class IntraPatternTracker:
                 vals: Tuple[int, ...]) -> List[Encoded]:
         """:meth:`encode` given ``key``'s run as looked up."""
         if run is None:
-            self._runs[key] = _RunState(index=1, base=vals, stride=None)
+            self.runs[key] = _RunState(index=1, base=vals, stride=None)
             return list(vals)
         if run.stride is None:
             stride = tuple(v - b for v, b in zip(vals, run.base))
@@ -115,7 +108,7 @@ class IntraPatternTracker:
             run.index += 1
             return [IterPattern(a, b) for a, b in zip(run.stride, run.base)]
         # run broken: restart
-        self._runs[key] = _RunState(index=1, base=vals, stride=None)
+        self.runs[key] = _RunState(index=1, base=vals, stride=None)
         return list(vals)
 
     def encode_many(self, key: Any, rows: Sequence[Sequence[int]],
@@ -145,7 +138,7 @@ class IntraPatternTracker:
         out: List[List[Encoded]] = []
         n = len(rows)
         p = 0  # rows consumed by continuing a pre-existing run
-        run = self._runs.get(key)
+        run = self.runs.get(key)
         if run is not None and len(run.base) == k:
             if run.stride is None:
                 # second element of the active run: always matches and
@@ -191,11 +184,11 @@ class IntraPatternTracker:
                     stride = tuple(int(v) for v in (W[s + 1] - W[s]))
                     pat = [IterPattern(a, b) for a, b in zip(stride, base)]
                     out.extend(list(pat) for _ in range(e - s - 1))
-                    self._runs[key] = _RunState(index=e - s, base=base,
-                                                stride=stride)
+                    self.runs[key] = _RunState(index=e - s, base=base,
+                                               stride=stride)
                 else:
-                    self._runs[key] = _RunState(index=1, base=base,
-                                                stride=None)
+                    self.runs[key] = _RunState(index=1, base=base,
+                                               stride=None)
         return out
 
 

@@ -1,8 +1,10 @@
 """The Recorder runtime (paper Sections 2 and 3).
 
 One ``Recorder`` instance per process (rank).  The generated tracing
-wrappers (``wrappers.py``) call :meth:`Recorder.record` from their epilogue;
-the record path performs, in order:
+wrappers (``wrappers.py``) call :meth:`Recorder.record` from their epilogue,
+which runs the function's record code (:func:`record_code`, generated once
+per spec from its argument roles, as the wrappers are); it performs, in
+order:
 
   * argument normalization by role (paths, unified handle ids, buffer
     lengths -- paper §2.2.1/§3.2.2),
@@ -27,8 +29,9 @@ import sys
 import threading
 import time
 import warnings
+import weakref
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence, Set, Tuple
+from typing import Any, Callable, Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
@@ -40,7 +43,7 @@ from .interprocess import (deserialize_rank_state, finalize_ranks,
                            merge_serialized_states, serialize_rank_state)
 from .patterns import IntraPatternTracker
 from .sequitur import Sequitur, concat_grammars
-from .specs import DATA_FUNCS, REGISTRY, FunctionRegistry, Role
+from .specs import DATA_FUNCS, REGISTRY, FnSpec, FunctionRegistry, Role
 from .timestamps import TimestampBuffer, compress_timestamps
 from . import selftrace, streaming, trace_format
 
@@ -240,6 +243,9 @@ class Recorder:
         self.rank = rank
         self.config = config or RecorderConfig()
         self.registry = registry
+        # func id -> its record code (record_code), shared by the
+        # registry's recorders
+        self._codes = _CODES.setdefault(registry, {})
         self.cst = CST()
         self.grammar = Sequitur()
         self.intra = IntraPatternTracker(enabled=self.config.intra_patterns)
@@ -335,9 +341,9 @@ class Recorder:
     def record(self, func_id: int, raw_args: tuple, ret: Any, depth: int,
                t0: int, t1: int) -> None:
         c0 = time.perf_counter_ns() if selftrace.on else 0
-        spec = self.registry.spec(func_id)
+        code = self._codes.get(func_id) or record_code(self.registry, func_id)
         with self._lock:
-            self._record_locked(spec, func_id, raw_args, ret, depth, t0, t1)
+            code(self, func_id, raw_args, ret, depth, t0, t1)
         if self._tls.depth == 0:
             # auto-flush only from top-level calls (a flush inside a layered
             # call would split parent and child records across epochs)
@@ -359,146 +365,39 @@ class Recorder:
                 selftrace.count(name, v)
             self._stage_sums = [0] * len(_STAGE_COUNTERS)
 
-    def _record_locked(self, spec, func_id: int, raw_args: tuple, ret: Any,
-                       depth: int, t0: int, t1: int) -> None:
-        traced = selftrace.on
-        if traced:
-            c1 = time.perf_counter_ns()
-        tidx = self._thread_index()
-        norm: List[Any] = []
-        offsets: List[int] = []
-        offset_slots: List[int] = []
-        handle_ids: List[int] = []
-        keyparts: List[Any] = []
-        keepable = True     # see _KEPT_TYPES
-        prefixes = self.config.path_prefixes
-        for i, arg in enumerate(raw_args):
-            role = spec.args[i].role if i < len(spec.args) else Role.VAL
-            if role == Role.PATH:
-                p = str(arg)
-                if prefixes is not None and not any(
-                        p.startswith(x) for x in prefixes):
-                    # filtered out: skip the record entirely; if this call
-                    # creates a handle, remember it as untracked
-                    if spec.ret_role == Role.HANDLE and ret is not None:
-                        self._untracked.add(ret)
-                    self.n_skipped += 1
-                    return
-                norm.append(p)
-                keyparts.append(p)
-            elif role == Role.HANDLE:
-                if arg in self._untracked:
-                    self.n_skipped += 1
-                    return
-                h = self._handles.get(arg)
-                if h is None:
-                    # handle from before tracing started: late-register
-                    h = self._alloc_handle()
-                    self._handles[arg] = h
-                norm.append(h)
-                handle_ids.append(h.id)
-            elif role == Role.OFFSET:
-                offsets.append(int(arg))
-                offset_slots.append(len(norm))
-                norm.append(None)  # placeholder, filled below
-            elif role == Role.BUF:
-                v = len(arg) if hasattr(arg, "__len__") else (
-                    int(arg) if isinstance(arg, int) else None)
-                norm.append(v)
-                keyparts.append(v)
-            else:  # SIZE / VAL
-                if type(arg) not in _KEPT_TYPES:
-                    keepable = False
-                norm.append(arg)
-                keyparts.append(arg)
-
-        # normalize the return value
-        is_err = isinstance(ret, tuple) and len(ret) == 2 and ret[0] == "err"
-        if spec.ret_role == Role.HANDLE and ret is not None and not is_err:
-            # layered opens (shard_open -> posix.open) return the same
-            # raw handle: they share one unified id (paper Section 3.2.2)
-            h = self._handles.get(ret)
-            if h is None:
-                h = self._alloc_handle()
-                self._handles[ret] = h
-            nret: Any = h
-        elif spec.ret_role == Role.BUF and hasattr(ret, "__len__"):
-            nret = len(ret)
-        else:
-            nret = ret
-        if isinstance(nret, Handle):
-            key_ret: Any = ("h", nret.id)
-        else:
-            key_ret = nret
-
-        # OFFSET-role returns (e.g. lseek's resulting offset) join the
-        # pattern run; they cannot be part of the pattern key then.
-        ret_is_offset = (spec.ret_role == Role.OFFSET
-                         and isinstance(nret, int) and not is_err)
-
-        # intra-process I/O pattern encoding (paper §3.2.1)
-        run = terminal = None
-        if offsets or ret_is_offset:
-            key = (func_id, tidx, tuple(handle_ids), tuple(keyparts),
-                   None if ret_is_offset else key_ret)
-            if ret_is_offset:
-                vals = offsets + [nret]
-            else:
-                vals = offsets
-                if type(nret) not in _KEPT_TYPES:
-                    keepable = False
-            run, encoded = self.intra.advance(
-                key, vals, depth if keepable else None)
-            if encoded is None:
-                # the call continues a run whose signature is interned:
-                # its terminal is the run's (the OFFSET-role return it
-                # would encode is an IterPattern: no data bytes)
-                terminal = run.terminal
-                if ret_is_offset:
-                    nret = None
-            else:
-                for slot, val in zip(offset_slots, encoded):
-                    norm[slot] = val
-                if ret_is_offset:
-                    nret = encoded[-1]
-
-        if traced:
-            c2 = c3 = time.perf_counter_ns()
-        if terminal is None:
-            sig = trace_format.make_signature(func_id, tidx, depth,
-                                              tuple(norm), nret)
-            terminal = self.cst.intern(sig)
-            if run is not None:
-                run.terminal, run.depth = terminal, depth
-            if traced:
-                c3 = time.perf_counter_ns()
-        elif traced:
-            self._stage_sums[6] += 1
-        self.grammar.push(terminal)
-        if traced:
-            sums = self._stage_sums
-            sums[2] += c2 - c1
-            sums[3] += c3 - c2
-            sums[4] += time.perf_counter_ns() - c3
-        if self.config.timestamps:
-            if self._epoch_first_tick is None:
-                self._epoch_first_tick = t0
-            self.timestamps.append(t0, t1,
-                                   self._data_bytes(spec, norm, nret))
-        self.n_records += 1
-
-    @staticmethod
-    def _data_bytes(spec, norm: List[Any], nret: Any) -> int:
-        """Data bytes moved by this call, for the per-timestamp-block byte
-        counters (exact windowed bandwidth).  Mirrors the signature-side
-        rule in ``traceview._SigInfo``: first BUF/SIZE int arg, else int
-        return, else 0 -- and only for the data-moving functions."""
-        if spec.name not in DATA_FUNCS:
-            return 0
-        for a, v in zip(spec.args, norm):
-            if a.role in (Role.BUF, Role.SIZE) and isinstance(v, int):
-                return v
-        return nret if isinstance(nret, int) else 0
+    def _intern_call(self, fid: int, tidx: int, depth: int, norm: List[Any],
+                     nret: Any, c1: int, key: Any = None, run: Any = None,
+                     keep: bool = False, slots: Tuple[int, ...] = (),
+                     vals: Sequence[int] = (), ret_off: bool = False
+                     ) -> Tuple[int, Any, int]:
+        """The record code's path for a call that does not continue a run
+        whose terminal it can reuse: its normalised arguments ``norm``
+        (None at the OFFSET-role ``slots``) and return ``nret``, and, where
+        the call has OFFSET-role values ``vals`` (the return last if
+        ``ret_off``), their pattern ``key`` and its ``run`` as looked up.
+        Encodes the values, interns the signature, and keeps its terminal
+        in the run it continues if ``keep``.  Returns the terminal, the
+        return as encoded, and the ns clock at the end of the signature
+        stage (0 unless the caller's stage clock ``c1`` is)."""
+        kept = None
+        if key is not None:
+            kept, encoded = self.intra.encode_run(key, run, vals, keep)
+            for slot, val in zip(slots, encoded):
+                norm[slot] = val
+            if ret_off:
+                nret = encoded[-1]
+        c2 = time.perf_counter_ns() if c1 else 0
+        terminal = self.cst.intern(trace_format.make_signature(
+            fid, tidx, depth, tuple(norm), nret))
+        if kept is not None:
+            kept.terminal, kept.depth = terminal, depth
+        if not c1:
+            return terminal, nret, 0
+        c3 = time.perf_counter_ns()
+        sums = self._stage_sums
+        sums[2] += c2 - c1
+        sums[3] += c3 - c2
+        return terminal, nret, c3
 
     def forget_handle(self, raw: Any) -> None:
         """Called by close-style wrappers after recording."""
@@ -1057,6 +956,192 @@ class Recorder:
             "tick_unit": "us",
             "tick_wrap": 2 ** 32,
         }
+
+
+# ---------------------------------------------------------------------------
+# per-function record code, generated from the spec (paper §2.1)
+# ---------------------------------------------------------------------------
+
+# registry -> {func id: record code for calls of its spec's arity,
+#              (func id, n): for calls of n arguments}
+_CODES: "weakref.WeakKeyDictionary[FunctionRegistry, Dict[Any, Callable]]" \
+    = weakref.WeakKeyDictionary()
+
+
+def record_code(registry: FunctionRegistry, fid: int,
+                nargs: Optional[int] = None) -> Callable:
+    """The record path of function ``fid`` for calls of ``nargs``
+    arguments (default: its spec's), as ``code(rec, fid, raw_args, ret,
+    depth, t0, t1)``, called under ``rec._lock``.  Generated once per
+    registry, function and arity from the spec's roles, so the roles are
+    not looked at again per call; the recorder's settings are read at
+    run time.  An argument past the spec's counts as VAL.
+
+    The code normalises each argument by its role (paths filtered by
+    ``path_prefixes``, handles given unified ids, buffers measured) and
+    the return value.  A call with OFFSET-role values looks its pattern
+    key's run up: if the run kept a terminal at this call depth and the
+    values are the run's next (``base + index * stride``), the call only
+    advances the run, pushes the terminal and appends its timestamp row.
+    Every other call goes through :meth:`Recorder._intern_call`.  A call
+    whose SIZE/VAL arguments or return (unless OFFSET-role) are not of a
+    type in ``_KEPT_TYPES`` neither keeps nor reuses a terminal."""
+    spec = registry.spec(fid)
+    codes = _CODES.setdefault(registry, {})
+    if nargs is None or nargs == len(spec.args):
+        nargs, key = len(spec.args), fid
+    else:
+        key = (fid, nargs)
+    code = codes.get(key)
+    if code is None:
+        ns: Dict[str, Any] = {"_st": selftrace, "_ns": time.perf_counter_ns,
+                              "_KEPT": _KEPT_TYPES, "Handle": Handle,
+                              "record_code": record_code}
+        exec(compile(_record_source(spec, nargs),  # noqa: S102 (§2.1)
+                     f"<recorder-record:{spec.name}/{nargs}>", "exec"), ns)
+        code = codes[key] = ns["record_call"]
+    return code
+
+
+def _tuple(items: Sequence[str]) -> str:
+    return "(" + ", ".join(items) + ("," if len(items) == 1 else "") + ")"
+
+
+def _record_source(spec: FnSpec, nargs: int) -> str:
+    """Source of :func:`record_code`'s function for ``spec`` at
+    ``nargs`` arguments."""
+    roles = [spec.args[i].role if i < len(spec.args) else Role.VAL
+             for i in range(nargs)]
+    out = [f"if len(raw_args) != {nargs}:",
+           "    return record_code(rec.registry, fid, len(raw_args))("
+           "rec, fid, raw_args, ret, depth, t0, t1)",
+           "c1 = _ns() if _st.on else 0",
+           "tidx = rec._tls.tidx",
+           "if tidx is None:",
+           "    tidx = rec._thread_index()"]
+    if nargs:
+        out.append(", ".join(f"a{i}" for i in range(nargs))
+                   + ("," if nargs == 1 else "") + " = raw_args")
+    if Role.PATH in roles:
+        out.append("prefixes = rec.config.path_prefixes")
+    skip = ["    rec.n_skipped += 1", "    return"]
+    # per argument: its value in the signature, and what joins the key
+    norm, hids, parts, offs, slots, kept, data = [], [], [], [], [], [], []
+    for i, role in enumerate(roles):
+        a, v = f"a{i}", f"v{i}"
+        if role == Role.PATH:
+            out += [f"{v} = str({a})",
+                    f"if prefixes is not None and not any("
+                    f"{v}.startswith(x) for x in prefixes):"]
+            # filtered out: skip the record; a handle the call
+            # creates is remembered as untracked
+            if spec.ret_role == Role.HANDLE:
+                out += ["    if ret is not None:",
+                        "        rec._untracked.add(ret)"]
+            out += skip
+            norm.append(v)
+            parts.append(v)
+        elif role == Role.HANDLE:
+            # a handle from before tracing started is registered late
+            out += [f"if {a} in rec._untracked:"] + skip + [
+                f"{v} = rec._handles.get({a})",
+                f"if {v} is None:",
+                f"    {v} = rec._handles[{a}] = rec._alloc_handle()"]
+            norm.append(v)
+            hids.append(f"{v}.id")
+        elif role == Role.OFFSET:
+            out.append(f"o{i} = int({a})")
+            slots.append(str(len(norm)))
+            offs.append(f"o{i}")
+            norm.append("None")
+        elif role == Role.BUF:
+            out.append(f"{v} = len({a}) if hasattr({a}, '__len__') else "
+                       f"(int({a}) if isinstance({a}, int) else None)")
+            norm.append(v)
+            parts.append(v)
+            data.append(v)
+        else:   # SIZE / VAL
+            kept.append(f"type({a}) in _KEPT")
+            norm.append(a)
+            parts.append(a)
+            if role == Role.SIZE:
+                data.append(a)
+    if spec.ret_role == Role.HANDLE:
+        # layered opens (shard_open -> posix.open) return the same raw
+        # handle: they share one unified id (paper Section 3.2.2)
+        out += ["if ret is not None and not (isinstance(ret, tuple) and "
+                "len(ret) == 2 and ret[0] == 'err'):",
+                "    nret = rec._handles.get(ret)",
+                "    if nret is None:",
+                "        nret = rec._handles[ret] = rec._alloc_handle()",
+                "else:",
+                "    nret = ret"]
+    elif spec.ret_role == Role.BUF:
+        out.append("nret = len(ret) if hasattr(ret, '__len__') else ret")
+    else:
+        out.append("nret = ret")
+    head = ["fid", "tidx", _tuple(hids), _tuple(parts)]
+    miss = (f"terminal, nret, c3 = rec._intern_call(fid, tidx, depth, "
+            f"[{', '.join(norm)}], nret, c1")
+
+    def run_block(indent: str, key_ret: str, vals: List[str], keep: List[str],
+                  ret_off: bool) -> List[str]:
+        """The pattern key's run looked up: a call whose ``vals`` are the
+        run's next, at the depth its terminal was kept at, only advances
+        it; any other call misses.  The run's length is compared too: a
+        call of another arity, or an OFFSET-role return that is not an
+        int, shares its key with values of another count."""
+        nxt = " and ".join(f"{x} == run.base[{k}] + run.index * "
+                           f"run.stride[{k}]" for k, x in enumerate(vals))
+        lines = [f"keep = {' and '.join(keep) or 'True'}",
+                 f"key = ({', '.join(head + [key_ret])})",
+                 "run = rec.intra.runs.get(key)",
+                 "if (keep and run is not None and run.depth == depth",
+                 "        and run.terminal is not None",
+                 f"        and len(run.base) == {len(vals)} and {nxt}):",
+                 "    run.index += 1",
+                 "    terminal = run.terminal",
+                 "    if c1:",
+                 "        c3 = _ns()",
+                 "        sums = rec._stage_sums",
+                 "        sums[2] += c3 - c1",
+                 "        sums[6] += 1"]
+        if ret_off:     # in a run, the return is an IterPattern: no bytes
+            lines.append("    nret = None")
+        lines += ["else:",
+                  f"    {miss}, key, run, keep, {_tuple(slots)}, "
+                  f"[{', '.join(vals)}], {ret_off})"]
+        return [indent + x for x in lines]
+
+    key_ret = "('h', nret.id) if isinstance(nret, Handle) else nret"
+    ret_kept = kept + ["type(nret) in _KEPT"]
+    plain = ([f"{miss})"] if not offs
+             else run_block("", key_ret, offs, ret_kept, False))
+    if spec.ret_role == Role.OFFSET:
+        # an int OFFSET-role return (lseek's resulting offset) joins the
+        # run; it cannot be part of the pattern key then
+        out += ["if isinstance(nret, int):"]
+        out += run_block("    ", "None", offs + ["nret"], kept, True)
+        out += ["else:"] + ["    " + x for x in plain]
+    else:
+        out += plain
+    # the data bytes moved (traceview._SigInfo's rule): the first int
+    # BUF/SIZE argument, else an int return, and only for DATA_FUNCS
+    nbytes = "0"
+    if spec.name in DATA_FUNCS:
+        nbytes = "nret if isinstance(nret, int) else 0"
+        for x in reversed(data):
+            nbytes = f"{x} if isinstance({x}, int) else ({nbytes})"
+    out += ["rec.grammar.push(terminal)",
+            "if c1:",
+            "    rec._stage_sums[4] += _ns() - c3",
+            "if rec.config.timestamps:",
+            "    if rec._epoch_first_tick is None:",
+            "        rec._epoch_first_tick = t0",
+            f"    rec.timestamps.append(t0, t1, {nbytes})",
+            "rec.n_records += 1"]
+    return ("def record_call(rec, fid, raw_args, ret, depth, t0, t1):\n"
+            + "".join(f"    {x}\n" for x in out))
 
 
 # ---------------------------------------------------------------------------

@@ -233,9 +233,9 @@ def test_encode_many_backend_matches_scalar_protocol():
         tr = IntraPatternTracker()
         got = tr.encode_many("k", rows, backend=b)
         assert got == ref, b
-        assert tr._runs.keys() == ref_tr._runs.keys()
-        assert all(vars(tr._runs[k]) == vars(ref_tr._runs[k])
-                   for k in tr._runs), b
+        assert tr.runs.keys() == ref_tr.runs.keys()
+        assert all(vars(tr.runs[k]) == vars(ref_tr.runs[k])
+                   for k in tr.runs), b
 
 
 # ---------------------------------------------------------------------------

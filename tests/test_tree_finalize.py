@@ -313,7 +313,7 @@ def test_encode_many_matches_sequential(vals, arity, seed):
     out_seq = [seq.encode("k", r) for r in rows]
     out_bat = bat.encode_many("k", rows)
     assert out_seq == out_bat
-    rs, rb = seq._runs.get("k"), bat._runs.get("k")
+    rs, rb = seq.runs.get("k"), bat.runs.get("k")
     assert (rs is None) == (rb is None)
     if rs is not None:
         assert (rs.index, rs.base, rs.stride) == (rb.index, rb.base, rb.stride)
