@@ -6,14 +6,12 @@ intra-process recognition the trace size must be FLAT in call count.
 Fig 5 (scaling): fixed call count, increasing nprocs; with inter-process
 recognition the trace size must be FLAT in process count.
 
-Finalize scaling: sweeps simulated rank counts x {flat, tree} topology x
-{python, vectorized} fit mode over synthesized IOR-shaped rank states and
-times the inter-process finalization.  For the tree topology the reported
-wall time is the *critical path* a real deployment would see -- the slowest
-leaf build (leaves are built concurrently, one per rank) plus the slowest
-merge of each O(log N) reduction round plus the root materialization --
-while ``cpu_s`` is the total sequential work.  Traces from every
-combination are checked byte-identical against the flat reference.
+Finalize scaling: sweeps simulated rank counts over synthesized
+IOR-shaped rank states and times the inter-process tree reduction.  The
+reported wall time is the *critical path* a real deployment would see --
+the slowest leaf build (leaves are built concurrently, one per rank) plus
+the slowest merge of each O(log N) reduction round plus the root
+materialization -- while ``cpu_s`` is the total sequential work.
 
 Outputs CSV to artifacts/bench/ior_{blocksize,scaling}.csv and JSON to
 artifacts/bench/finalize_scaling.json.
@@ -28,11 +26,10 @@ import os
 import shutil
 import tempfile
 import time
-from typing import Dict, List
+from typing import List
 
-from repro.core import trace_format
-from repro.core.interprocess import (finalize_ranks, make_rank_state,
-                                     materialize_state, merge_rank_states)
+from repro.core.interprocess import (make_rank_state, materialize_state,
+                                     merge_rank_states)
 from repro.core.recorder import RecorderConfig
 from repro.core.specs import REGISTRY
 
@@ -87,94 +84,53 @@ def scaling(nprocs_list=(4, 16, 64, 256), n_calls: int = 256) -> List[dict]:
     return rows
 
 
-def _write_trace_tmp(merge, cfgs, nprocs: int) -> str:
-    d = tempfile.mkdtemp()
-    trace_format.write_trace(
-        d, registry=REGISTRY, merged_cst=merge.merged_entries,
-        unique_cfgs=cfgs.unique_cfgs, cfg_index=cfgs.cfg_index,
-        rank_timestamps=[b""] * nprocs, meta_extra={})
-    return d
-
-
-def _traces_identical(d1: str, d2: str) -> bool:
-    for name in ("merged_cst.bin", "unique_cfgs.bin", "cfg_index.bin",
-                 "timestamps.bin"):
-        with open(os.path.join(d1, name), "rb") as f1, \
-                open(os.path.join(d2, name), "rb") as f2:
-            if f1.read() != f2.read():
-                return False
-    return True
-
-
 def finalize_scaling(nprocs_list=(16, 64, 256, 1024, 4096),
                      n_groups: int = 32, n_calls: int = 64,
                      pattern: str = "linear") -> List[dict]:
-    """Time flat vs tree finalization over synthesized rank states."""
+    """Time the tree finalization over synthesized rank states."""
     rows: List[dict] = []
     for nprocs in nprocs_list:
         csts, cfgs = synth_rank_states(nprocs, n_groups=n_groups,
                                        n_calls=n_calls, pattern=pattern)
-        ref_dir = None
         gc.disable()  # GC pauses would dominate the per-round maxima
         try:
-            for topology in ("flat", "tree"):
-                for fit_mode in ("python", "vectorized"):
-                    gc.collect()
-                    if topology == "flat":
+            gc.collect()
+            # leaves are per-rank parallel work on a real run: the
+            # critical path counts the slowest one only
+            leaf_times = []
+            states = []
+            for r in range(nprocs):
+                t0 = time.perf_counter()
+                states.append(make_rank_state(r, csts[r], cfgs[r], REGISTRY))
+                leaf_times.append(time.perf_counter() - t0)
+            cpu = sum(leaf_times)
+            wall = max(leaf_times)
+            while len(states) > 1:
+                nxt, round_times = [], []
+                for i in range(0, len(states), 2):
+                    if i + 1 < len(states):
                         t0 = time.perf_counter()
-                        merge, cfgres = finalize_ranks(
-                            csts, cfgs, REGISTRY, fit_mode=fit_mode)
-                        wall = cpu = time.perf_counter() - t0
+                        nxt.append(merge_rank_states(states[i],
+                                                     states[i + 1]))
+                        round_times.append(time.perf_counter() - t0)
                     else:
-                        # leaves are per-rank parallel work on a real run:
-                        # critical path counts the slowest one only
-                        leaf_times = []
-                        states = []
-                        for r in range(nprocs):
-                            t0 = time.perf_counter()
-                            states.append(make_rank_state(
-                                r, csts[r], cfgs[r], REGISTRY))
-                            leaf_times.append(time.perf_counter() - t0)
-                        cpu = sum(leaf_times)
-                        wall = max(leaf_times)
-                        while len(states) > 1:
-                            nxt, round_times = [], []
-                            for i in range(0, len(states), 2):
-                                if i + 1 < len(states):
-                                    t0 = time.perf_counter()
-                                    nxt.append(merge_rank_states(
-                                        states[i], states[i + 1]))
-                                    round_times.append(
-                                        time.perf_counter() - t0)
-                                else:
-                                    nxt.append(states[i])
-                            states = nxt
-                            cpu += sum(round_times)
-                            wall += max(round_times)
-                        t0 = time.perf_counter()
-                        merge, cfgres = materialize_state(
-                            states[0], fit_mode=fit_mode)
-                        dt = time.perf_counter() - t0
-                        cpu += dt
-                        wall += dt
-                    d = _write_trace_tmp(merge, cfgres, nprocs)
-                    if ref_dir is None:
-                        ref_dir, identical = d, True
-                    else:
-                        identical = _traces_identical(ref_dir, d)
-                        shutil.rmtree(d, ignore_errors=True)
-                    rows.append({
-                        "nprocs": nprocs, "topology": topology,
-                        "fit_mode": fit_mode, "pattern": pattern,
-                        "n_groups": n_groups, "n_calls": n_calls,
-                        "wall_s": round(wall, 6), "cpu_s": round(cpu, 6),
-                        "cst_entries": len(merge.merged_entries),
-                        "identical_to_flat": identical,
-                    })
+                        nxt.append(states[i])
+                states = nxt
+                cpu += sum(round_times)
+                wall += max(round_times)
+            t0 = time.perf_counter()
+            merge, _ = materialize_state(states[0])
+            dt = time.perf_counter() - t0
+            cpu += dt
+            wall += dt
         finally:
             gc.enable()
-            if ref_dir:
-                shutil.rmtree(ref_dir, ignore_errors=True)
+        rows.append({
+            "nprocs": nprocs, "pattern": pattern,
+            "n_groups": n_groups, "n_calls": n_calls,
+            "wall_s": round(wall, 6), "cpu_s": round(cpu, 6),
+            "cst_entries": len(merge.merged_entries),
+        })
     return rows
 
 
@@ -207,16 +163,9 @@ def main(fast: bool = False) -> List[str]:
                           n_calls=16 if fast else 64)
     with open(os.path.join(ART, "finalize_scaling.json"), "w") as f:
         json.dump(fs, f, indent=1)
-    by: Dict[tuple, dict] = {(r["nprocs"], r["topology"], r["fit_mode"]): r
-                             for r in fs}
-    peak = max(r["nprocs"] for r in fs)
-    seed_flat = by[(peak, "flat", "python")]["wall_s"]
-    tree_vec = by[(peak, "tree", "vectorized")]["wall_s"]
-    speedup = seed_flat / max(tree_vec, 1e-9)
-    ident = all(r["identical_to_flat"] for r in fs)
-    out.append(f"finalize_scaling,nprocs={peak},flat_python_s={seed_flat},"
-               f"tree_vectorized_s={tree_vec},speedup={speedup:.1f}x,"
-               f"identical={ident}")
+    peak = max(fs, key=lambda r: r["nprocs"])
+    out.append(f"finalize_scaling,nprocs={peak['nprocs']},"
+               f"tree_s={peak['wall_s']},cst_entries={peak['cst_entries']}")
     return out
 
 

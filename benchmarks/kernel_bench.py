@@ -5,11 +5,11 @@ the portable numbers are (a) the XLA-path wall times on this host and
 (b) the analytic FLOP/byte counts that feed the Section Roofline analysis.
 
 ``encode_sweep`` measures the trace-encode hot path (delta+zigzag, varint
-packing, rank-linear column fitting) across batch sizes under every
-``encode_backend`` and writes ``artifacts/bench/encode_kernels.json`` with
-the per-backend crossover points (smallest batch where the batched backend
-beats the scalar Python encoder) and the speedup at the 64k-record batch
-the streaming flusher typically hands the encoder.
+packing) across batch sizes under every ``encode_backend`` and writes
+``artifacts/bench/encode_kernels.json`` with the per-backend crossover
+points (smallest batch where the batched backend beats the scalar Python
+encoder) and the speedup at the 64k-record batch the streaming flusher
+typically hands the encoder.
 """
 
 from __future__ import annotations
@@ -26,7 +26,6 @@ import numpy as np
 
 from repro.core import encode_backend as eb
 from repro.core.encoding import pack_uvarints
-from repro.core.interprocess import batch_fit_columns
 from repro.core.timestamps import delta_zigzag_encode
 from repro.kernels.delta_encode.ops import delta_zigzag
 from repro.kernels.flash_attention.ref import attention_ref
@@ -54,7 +53,7 @@ def _wall(fn, reps: int) -> float:
     return (time.perf_counter() - t0) / reps
 
 
-_ENCODE_OPS = ("delta_zigzag", "pack_uvarints", "fit_columns")
+_ENCODE_OPS = ("delta_zigzag", "pack_uvarints")
 
 
 def encode_sweep(smoke: bool = False) -> Dict[str, Any]:
@@ -75,11 +74,6 @@ def encode_sweep(smoke: bool = False) -> Dict[str, Any]:
         ticks = np.cumsum(rng.randint(0, 1000, size=n)).astype(np.int64)
         vals = [int(v) for v in rng.randint(0, 1 << 48, size=n,
                                             dtype=np.uint64)]
-        ranks = 16
-        ncols = max(1, n // ranks)
-        cols = [[b + r * a for r in range(ranks)]
-                for a, b in zip(rng.randint(1, 9, size=ncols),
-                                rng.randint(0, 10**6, size=ncols))]
         reps = 1 if n >= (1 << 16) else 3
         for b in backends:
             if b == "pallas" and n > pallas_cap:
@@ -88,8 +82,6 @@ def encode_sweep(smoke: bool = False) -> Dict[str, Any]:
                 lambda b=b, t=ticks: eb.delta_zigzag(t, b), reps)
             timings["pack_uvarints"][b][str(n)] = _wall(
                 lambda b=b, v=vals: pack_uvarints(v, backend=b), reps)
-            timings["fit_columns"][b][str(n)] = _wall(
-                lambda b=b, c=cols: batch_fit_columns(c, backend=b), reps)
 
     crossover: Dict[str, Dict[str, Optional[int]]] = {}
     speedup_64k: Dict[str, Dict[str, Optional[float]]] = {}

@@ -17,21 +17,20 @@
 
 Each driver runs ONE rank's call stream against a fresh Recorder (or a
 baseline adapter) and returns the tool's local state; the caller loops
-ranks and feeds ``finalize_ranks`` (or ``tree_finalize_ranks``) --
-bit-identical to what rank 0 of a real MPI run computes after the gather
-(core/comm.py notes).
+ranks and feeds ``tree_finalize_ranks`` -- bit-identical to what rank 0
+of a real MPI run computes after the reduction (core/comm.py notes).
 """
 
 from __future__ import annotations
 
 import os
 import random
-from typing import Any, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Tuple
 
 from repro.core.apis import framework as frame
 from repro.core.apis import posix, shardio
 from repro.core.encoding import Handle, encode_signature
-from repro.core.interprocess import finalize_ranks, tree_finalize_ranks
+from repro.core.interprocess import tree_finalize_ranks
 from repro.core.patterns import IntraPatternTracker
 from repro.core.recorder import Recorder, RecorderConfig, attach, detach
 from repro.core.sequitur import Sequitur
@@ -226,17 +225,9 @@ def synth_rank_states(nprocs: int, *, n_groups: int = 32, n_calls: int = 64,
 
 
 def run_ranks(workload, nprocs: int, recorder_config: RecorderConfig,
-              finalize_topology: Optional[str] = None,
-              fit_mode: str = "vectorized", **kw) -> Dict[str, Any]:
+              **kw) -> Dict[str, Any]:
     """Run ``workload(tool, rank, nprocs, **kw)`` for every simulated rank
-    with a fresh Recorder, then the inter-process stage; returns sizes.
-
-    ``finalize_topology`` (default: honor
-    ``recorder_config.finalize_topology``) and ``fit_mode`` select the
-    finalize implementation (flat gather vs tree reduction, scalar vs
-    vectorized fitting); all combinations produce identical sizes."""
-    if finalize_topology is None:
-        finalize_topology = recorder_config.finalize_topology
+    with a fresh Recorder, then the inter-process stage; returns sizes."""
     states = []
     n_records = 0
     for r in range(nprocs):
@@ -247,11 +238,8 @@ def run_ranks(workload, nprocs: int, recorder_config: RecorderConfig,
     csts = [s[0] for s in states]
     cfgs = [s[1] for s in states]
     ts = [s[2] for s in states]
-    fin = (tree_finalize_ranks if finalize_topology == "tree"
-           else finalize_ranks)
-    merge, cfgres = fin(
-        csts, cfgs, REGISTRY,
-        inter_patterns=recorder_config.inter_patterns, fit_mode=fit_mode)
+    merge, cfgres = tree_finalize_ranks(
+        csts, cfgs, REGISTRY, inter_patterns=recorder_config.inter_patterns)
     cst_bytes = sum(len(e) + 2 for e in merge.merged_entries)
     cfg_bytes = sum(len(c) + 2 for c in cfgres.unique_cfgs)
     index_bytes = 2 * len(cfgres.cfg_index)

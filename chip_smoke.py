@@ -122,8 +122,7 @@ class KernelCounter:
 
     OPS = {
         "repro.kernels.delta_encode.ops": (
-            "delta_zigzag", "delta_zigzag_varint", "uvarint_encode64",
-            "fit_columns"),
+            "delta_zigzag", "delta_zigzag_varint", "uvarint_encode64"),
         "repro.kernels.grammar_stats.ops": (
             "row_boundaries", "histogram", "digram_codes"),
     }
@@ -314,16 +313,9 @@ def _encode_inputs(rng, n: int) -> dict:
                        endpoint=True) >> (np.uint64(64) - widths)
     runs = np.repeat(rng.integers(-5, 5, n // 3 + 1), 3)[:n]
     V = np.stack([runs, np.roll(runs, 7), rng.integers(0, 2, n)], axis=1)
-    ranks = 64
-    cols = n // ranks
-    base = rng.integers(-(1 << 29), 1 << 29, (cols, 1))
-    slope = rng.integers(-4096, 4096, (cols, 1))
-    kind = rng.integers(0, 3, (cols, 1))
-    F = base + np.where(kind == 0, 0, slope) * np.arange(ranks)
-    F = np.where(kind == 2, F + rng.integers(0, 2, (cols, ranks)), F)
     terms = rng.integers(0, 1000, n)
     terms[: n // 4] = 7
-    return {"ticks": ticks, "u64": u64, "V": V, "F": F, "terms": terms}
+    return {"ticks": ticks, "u64": u64, "V": V, "terms": terms}
 
 
 def _check_ops(n: int, rng, counter: KernelCounter) -> None:
@@ -338,7 +330,6 @@ def _check_ops(n: int, rng, counter: KernelCounter) -> None:
         "pack_uvarints_batch": lambda b: eb.pack_uvarints_batch(x["u64"], b),
         "run_boundaries": lambda b: eb.run_boundaries(x["V"], b),
         "run_boundaries_1col": lambda b: eb.run_boundaries(x["terms"], b),
-        "fit_classify": lambda b: eb.fit_classify(x["F"], b),
         "terminal_histogram": lambda b: eb.terminal_histogram(
             x["terms"], 1000, b),
         "terminal_histogram_5000": lambda b: eb.terminal_histogram(
@@ -354,9 +345,7 @@ def _check_ops(n: int, rng, counter: KernelCounter) -> None:
         dt = time.perf_counter() - t0
         hits = counter.since(before)
         check(sum(hits.values()) == 1, f"{name} n={n}: kernel calls {hits}")
-        if isinstance(want, tuple):
-            same = all(np.array_equal(a, b) for a, b in zip(want, got))
-        elif isinstance(want, np.ndarray):
+        if isinstance(want, np.ndarray):
             same = want.dtype == got.dtype and np.array_equal(want, got)
         else:
             same = want == got

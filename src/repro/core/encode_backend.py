@@ -1,8 +1,8 @@
-"""Backend dispatch for the batched encode/fit hot paths (ROADMAP dir. 2).
+"""Backend dispatch for the batched encode hot paths (ROADMAP dir. 2).
 
 The arithmetic-dense stages of the tracing pipeline -- timestamp
-delta+zigzag, varint packing, arithmetic-run boundary detection and
-rank-linear column fitting -- exist in three interchangeable
+delta+zigzag, varint packing, arithmetic-run boundary detection and the
+symbol-stream histograms -- exist in three interchangeable
 implementations:
 
 ``python``
@@ -24,8 +24,8 @@ implementations:
 the Python loop (below NumPy's fixed per-call overhead), everything else
 runs NumPy, and batches of ``PALLAS_MIN_BATCH``+ move to the kernels when
 a non-CPU device is present.  Every backend produces byte-identical
-output -- the switch is purely a performance knob
-(``RecorderConfig.encode_backend`` / ``RECORDER_ENCODE_BACKEND``).
+output.  A caller picks one per call (``backend=``; ``None`` means
+``auto``), and the Recorder passes ``RecorderConfig.encode_backend``.
 
 jax is imported lazily: the ``python`` and ``numpy`` paths must work (and
 the core package must import) on hosts without a usable jax install.
@@ -33,7 +33,7 @@ the core package must import) on hosts without a usable jax install.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -47,27 +47,9 @@ BACKENDS = ("auto", "python", "numpy", "pallas")
 NUMPY_MIN_BATCH = 64         # below: NumPy call overhead beats the loop win
 PALLAS_MIN_BATCH = 1 << 16   # below: kernel launch + transfer dominates
 
-_U64_MAX = (1 << 64) - 1
 _I32_SAFE = 1 << 31
-_I32_DIFF_SAFE = 1 << 30     # int32 differences of such values never wrap
 
-_default_backend = "auto"
 _accel: Optional[bool] = None
-
-
-def default_backend() -> str:
-    return _default_backend
-
-
-def set_default_backend(backend: str) -> None:
-    """Set the module-wide default used when callers pass ``backend=None``
-    (the Recorder threads its config through explicitly; this knob serves
-    benchmarks and ad-hoc analysis code)."""
-    global _default_backend
-    if backend not in BACKENDS:
-        raise ValueError(f"encode backend must be one of {BACKENDS}, "
-                         f"got {backend!r}")
-    _default_backend = backend
 
 
 def has_accelerator() -> bool:
@@ -93,8 +75,8 @@ def interpret_mode() -> bool:
 
 def resolve(backend: Optional[str], n: int) -> str:
     """Effective backend for a batch of ``n`` elements: explicit choices
-    win; ``auto`` applies the size crossover."""
-    b = backend if backend is not None else _default_backend
+    win; ``auto`` (or ``None``) applies the size crossover."""
+    b = backend if backend is not None else "auto"
     if b not in BACKENDS:
         raise ValueError(f"encode backend must be one of {BACKENDS}, "
                          f"got {b!r}")
@@ -305,7 +287,7 @@ def run_boundaries(V: np.ndarray, backend: Optional[str] = None
                    ) -> np.ndarray:
     """Row-change mask of a (n, k) matrix: ``mask[i]`` iff row i differs
     from row i-1 (``mask[0]`` always True).  The shared building block of
-    ``interprocess.arith_segments`` (over row diffs) and
+    ``patterns.arith_segments`` (over row diffs) and
     ``Sequitur.push_stream`` (over the raw terminal column)."""
     V = np.asarray(V)
     if V.ndim == 1:
@@ -330,33 +312,6 @@ def run_boundaries(V: np.ndarray, backend: Optional[str] = None
         if n > 1:
             mask[1:] = (V[1:] != V[:-1]).any(axis=1)
         return mask
-
-
-# ---------------------------------------------------------------------------
-# rank-linear column classification (interprocess.batch_fit_columns)
-# ---------------------------------------------------------------------------
-
-
-def fit_classify(V: np.ndarray, backend: Optional[str] = None
-                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Per-column (const_mask, linear_mask, first_diff) of a (C, R) int64
-    value matrix with R >= 2 -- the vectorized core of the rank-linear
-    fitter.  The pallas path runs one kernel call over column tiles and
-    falls back to NumPy when the kernel's int32 differences could wrap."""
-    eff = ("pallas" if backend == "pallas"
-           and np.abs(V).max(initial=0) < _I32_DIFF_SAFE else "numpy")
-    with _op("fit_classify", eff, V.size):
-        if eff == "pallas":
-            import jax.numpy as jnp
-            from ..kernels.delta_encode.ops import fit_columns
-            flags, d0 = fit_columns(jnp.asarray(V.astype(np.int32)),
-                                    interpret=interpret_mode())
-            flags = np.asarray(flags)
-            return flags == 1, flags == 2, np.asarray(d0).astype(np.int64)
-        d = V[:, 1:] - V[:, :-1]
-        const = (d == 0).all(axis=1)
-        linear = (d == d[:, :1]).all(axis=1) & (d[:, 0] != 0)
-        return const, linear, d[:, 0]
 
 
 # ---------------------------------------------------------------------------

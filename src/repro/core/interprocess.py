@@ -14,53 +14,39 @@ pass
   4. remaps every rank's CFG terminals and deduplicates identical CFGs
      (paper Fig 3d: unique-CFGs file + CFG-index file + merged-CST file).
 
-Two finalize topologies are provided:
+The pass runs as a hierarchical reduction.  Each rank builds a compact
+:class:`RankState` from its local CST/CFG (:func:`make_rank_state`);
+adjacent *contiguous* rank blocks are then merged pairwise
+(:func:`merge_rank_states`) in O(log N) rounds -- through
+``Comm.reduce_tree`` on real runs (a one-shot finalize and every streaming
+flush), or :func:`tree_reduce_states` on simulated rank lists.  A merged
+state keeps, per masked-signature occurrence group, either an exact
+*linear summary* (base + slope per offset slot, O(1) per group regardless
+of block size) or -- only once linearity is broken -- the explicit
+per-rank offsets.  Identical per-rank terminal streams are deduplicated
+inside the state, so for SPMD workloads the state size is constant in the
+number of ranks.  :func:`materialize_state` finally emits the merged CST +
+deduped CFGs.  States serialize to stable bytes
+(:func:`serialize_rank_state`) for transport between tree hops.
 
-``flat``
-    The original gather-at-root pass: every rank's CST/CFG lands on rank 0
-    and :func:`finalize_ranks` runs the three passes above over all ranks at
-    once.  O(ranks x calls) work on a single process; kept as the bit-compat
-    reference and for tiny worlds.
+The reference is the plain gather-at-root merge in
+``tests/_flat_merge_ref.py``: every rank's CST/CFG on one process, each
+group fitted by a scalar loop over the ranks.  The reduction writes the
+same bytes (property-tested in ``tests/test_tree_finalize.py``), with one
+documented divergence: offset leaves that are not plain ``int``s (e.g.
+``bool``) are never rank-fitted here, while the reference coerces them
+through ``int()``.  The runtime record path coerces offsets to ``int``
+before encoding, so real traces are unaffected.
 
-``tree`` (default in :class:`~repro.core.recorder.RecorderConfig`)
-    A hierarchical reduction.  Each rank builds a compact
-    :class:`RankState` from its local CST/CFG (:func:`make_rank_state`);
-    adjacent *contiguous* rank blocks are then merged pairwise
-    (:func:`merge_rank_states`) in O(log N) rounds -- through
-    ``Comm.reduce_tree`` on real runs, or :func:`tree_reduce_states` on
-    simulated rank lists.  A merged state keeps, per masked-signature
-    occurrence group, either an exact *linear summary* (base + slope per
-    offset slot, O(1) per group regardless of block size) or -- only once
-    linearity is broken -- the explicit per-rank offsets.  Identical
-    per-rank terminal streams are deduplicated inside the state, so for
-    SPMD workloads the state size is constant in the number of ranks.
-    :func:`materialize_state` finally emits a merged CST + deduped CFGs
-    that are **byte-identical** to the flat pass (property-tested in
-    ``tests/test_tree_finalize.py``).  States serialize to stable bytes
-    (:func:`serialize_rank_state`) for transport between tree hops.
-
-    One documented divergence: offset leaves that are not plain ``int``s
-    (e.g. ``bool``) are never rank-fitted by the tree path, while the flat
-    pass coerces them through ``int()``.  The runtime record path coerces
-    offsets to ``int`` before encoding, so real traces are unaffected.
-
-Rank-linear fitting is available in two modes: ``python`` (the original
-per-occurrence scalar loop) and ``vectorized`` (default; NumPy batched
-slope/intercept fitting over every candidate column at once,
-:func:`batch_fit_columns`).  Both produce identical results; the benchmark
-``benchmarks/ior_pattern.py::finalize_scaling`` sweeps topology x fit mode.
-
-All functions here are pure (lists in, lists out); the SPMD wrapper in
-``recorder.py`` moves data through a ``Comm``, and the benchmark drivers call
-these directly on simulated rank states.
+All functions here are pure (lists in, lists out); the SPMD wrappers in
+``recorder.py`` and ``streaming.py`` move data through a ``Comm``, and the
+benchmark drivers call these directly on simulated rank states.
 """
 
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Sequence, Tuple
-
-import numpy as np
+from typing import Any, Dict, List, Optional, Tuple
 
 from .encoding import (IterPattern, RankPattern, decode_signature,
                        decode_value, encode_signature, encode_value,
@@ -103,196 +89,7 @@ def _masked_bytes(func_id: int, tid: int, depth: int, masked: tuple, ret: Any,
 
 
 # ---------------------------------------------------------------------------
-# rank-linear fitting (scalar + vectorized)
-# ---------------------------------------------------------------------------
-
-
-def _fit_component(values: Sequence[int]) -> Optional[Any]:
-    """Fit ``v_r = r*a + b`` over ranks; int if constant, RankPattern if
-    linear with a != 0, None if not linear."""
-    v0 = values[0]
-    if all(v == v0 for v in values):
-        return int(v0)
-    if len(values) < 2:
-        return None
-    a = values[1] - values[0]
-    if a == 0:
-        return None
-    for r, v in enumerate(values):
-        if v != v0 + r * a:
-            return None
-    return RankPattern(a, v0)
-
-
-# offsets larger than this cannot be diffed safely in int64
-_I64_SAFE = 1 << 62
-
-
-def batch_fit_columns(columns: List[Sequence[int]],
-                      backend: Optional[str] = None) -> List[Optional[Any]]:
-    """Vectorized :func:`_fit_component` over many equal-length columns.
-
-    One pass classifies every column as constant (-> int), exactly
-    rank-linear with nonzero slope (-> RankPattern) or neither (-> None).
-    ``backend`` picks the classifier (``encode_backend.fit_classify``:
-    NumPy, or a single pallas_call over padded column tiles); results are
-    identical.  Falls back to the scalar loop when values do not fit
-    safely in int64.
-    """
-    if not columns:
-        return []
-    try:
-        V = np.asarray(columns, dtype=np.int64)
-    except (OverflowError, ValueError, TypeError):
-        return [_fit_component(c) for c in columns]
-    if V.ndim != 2 or np.abs(V).max(initial=0) >= _I64_SAFE:
-        return [_fit_component(c) for c in columns]
-    if V.shape[1] < 2:
-        return [int(c[0]) for c in columns]
-    from . import encode_backend as _eb
-    eff = _eb.resolve(backend, V.size)
-    if eff == "python":
-        return [_fit_component(c) for c in columns]
-    const, linear, d0 = _eb.fit_classify(V, eff)
-    d = d0[:, None]  # only d[:, 0] is consumed below
-    out: List[Optional[Any]] = []
-    for i in range(V.shape[0]):
-        if const[i]:
-            out.append(int(V[i, 0]))
-        elif linear[i]:
-            out.append(RankPattern(int(d[i, 0]), int(V[i, 0])))
-        else:
-            out.append(None)
-    return out
-
-
-def _fit_offsets(per_rank: List[tuple]) -> Optional[tuple]:
-    """Fit each offset slot across ranks.  ``per_rank[r]`` is the tuple of
-    offset values of rank r for this occurrence.  Values are ints or
-    IterPattern with int components."""
-    n_slots = len(per_rank[0])
-    if any(len(v) != n_slots for v in per_rank):
-        return None
-    out = []
-    for s in range(n_slots):
-        col = [pr[s] for pr in per_rank]
-        if all(isinstance(v, int) for v in col):
-            fit = _fit_component(col)  # type: ignore[arg-type]
-            if fit is None:
-                return None
-            out.append(fit)
-        elif all(isinstance(v, IterPattern) for v in col):
-            a_fit = _fit_component([int(v.a) for v in col])  # type: ignore[union-attr]
-            b_fit = _fit_component([int(v.b) for v in col])  # type: ignore[union-attr]
-            if a_fit is None or b_fit is None:
-                return None
-            out.append(IterPattern(a_fit, b_fit))
-        else:
-            return None  # mixed kinds across ranks: no merge
-    return tuple(out)
-
-
-def _fit_offsets_batch(all_per_rank: List[List[tuple]],
-                       backend: Optional[str] = None
-                       ) -> List[Optional[tuple]]:
-    """Batched :func:`_fit_offsets`: gather every int / IterPattern-component
-    column from every candidate group, fit them in one vectorized pass, then
-    reassemble per-group fits.  Result-equivalent to the scalar path."""
-    columns: List[List[int]] = []
-    plans: List[Optional[List[tuple]]] = []
-    for per_rank in all_per_rank:
-        n_slots = len(per_rank[0])
-        if any(len(v) != n_slots for v in per_rank):
-            plans.append(None)
-            continue
-        desc: List[tuple] = []
-        ok = True
-        for s in range(n_slots):
-            col = [pr[s] for pr in per_rank]
-            if all(isinstance(v, int) for v in col):
-                desc.append(("i", len(columns)))
-                columns.append(col)  # type: ignore[arg-type]
-            elif all(isinstance(v, IterPattern) for v in col):
-                ia = len(columns)
-                columns.append([int(v.a) for v in col])  # type: ignore[union-attr]
-                ib = len(columns)
-                columns.append([int(v.b) for v in col])  # type: ignore[union-attr]
-                desc.append(("p", ia, ib))
-            else:
-                ok = False
-                break
-        plans.append(desc if ok else None)
-    col_fits = batch_fit_columns(columns, backend=backend)
-    out: List[Optional[tuple]] = []
-    for plan in plans:
-        if plan is None:
-            out.append(None)
-            continue
-        fit: List[Any] = []
-        for d in plan:
-            if d[0] == "i":
-                f = col_fits[d[1]]
-                if f is None:
-                    fit = []
-                    break
-                fit.append(f)
-            else:
-                fa, fb = col_fits[d[1]], col_fits[d[2]]
-                if fa is None or fb is None:
-                    fit = []
-                    break
-                fit.append(IterPattern(fa, fb))
-        out.append(tuple(fit) if fit else None)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# arithmetic-run segmentation (the vectorized-fitting building block shared
-# with patterns.IntraPatternTracker.encode_many, which imports it)
-# ---------------------------------------------------------------------------
-
-
-def arith_segments(V: np.ndarray,
-                   backend: Optional[str] = None) -> List[Tuple[int, int]]:
-    """Greedy arithmetic-run segmentation of a (n, k) value matrix.
-
-    Returns half-open ``(start, end)`` element segments such that within a
-    segment every consecutive row difference equals the segment's first
-    difference (the run stride), mirroring the streaming protocol of
-    ``IntraPatternTracker``: a run's stride is set by its second element and
-    the run breaks at the first non-matching row.  ``backend`` dispatches
-    the change-point scan (``encode_backend.run_boundaries`` over the diff
-    rows); segmentation is identical across backends.
-    """
-    n = len(V)
-    if n == 0:
-        return []
-    if n == 1:
-        return [(0, 1)]
-    d = V[1:] - V[:-1]
-    if d.ndim == 1:
-        d = d[:, None]
-    # cp[j] for j >= 1: diff j differs from diff j-1
-    from . import encode_backend as _eb
-    mask = _eb.run_boundaries(d, backend)
-    mask[0] = False  # position 0 is forced True by the boundary op
-    cp = np.flatnonzero(mask)
-    segs: List[Tuple[int, int]] = []
-    s = 0
-    while s < n:
-        if s >= n - 1:
-            segs.append((s, n))
-            break
-        # largest run of equal diffs starting at diff index s
-        k = int(np.searchsorted(cp, s, side="right"))
-        c = int(cp[k]) if k < len(cp) else n - 1
-        segs.append((s, c + 1))
-        s = c + 1
-    return segs
-
-
-# ---------------------------------------------------------------------------
-# CST merge (flat topology)
+# merge results
 # ---------------------------------------------------------------------------
 
 
@@ -301,95 +98,6 @@ class MergeResult:
     merged_entries: List[bytes]          # the merged CST, terminal order
     remaps: List[Dict[int, int]]         # per rank: old terminal -> new
     n_rank_patterns: int                 # how many entries used RankPattern
-
-
-def merge_csts(rank_csts: List[List[bytes]], registry: FunctionRegistry,
-               inter_patterns: bool = True, fit_mode: str = "vectorized"
-               ) -> MergeResult:
-    """Merge per-rank CSTs into one (paper §3.3.1).
-
-    ``fit_mode`` selects the rank-linear fitter: ``"python"`` (per-group
-    scalar loop), ``"vectorized"`` (NumPy batch) or ``"pallas"`` (the
-    ``kernels/delta_encode`` column-fit kernel, interpret-mode on CPU).
-    Output is identical across modes.
-    """
-    nranks = len(rank_csts)
-    # -- pass 1: decode + group by (masked signature, occurrence index) ------
-    decoded: List[List[tuple]] = []        # [rank][t] = (masked_key, parts)
-    groups: Dict[Tuple[bytes, int], Dict[int, tuple]] = {}
-    group_order: List[Tuple[bytes, int]] = []
-    for r, cst in enumerate(rank_csts):
-        occ_counter: Dict[bytes, int] = {}
-        rank_rows = []
-        for t, sig in enumerate(cst):
-            (func_id, tid, depth, masked, ret, offsets,
-             ret_masked) = _split_offsets(registry, sig)
-            mkey = _masked_bytes(func_id, tid, depth, masked, ret, ret_masked)
-            j = occ_counter.get(mkey, 0)
-            occ_counter[mkey] = j + 1
-            gkey = (mkey, j)
-            g = groups.get(gkey)
-            if g is None:
-                g = {}
-                groups[gkey] = g
-                group_order.append(gkey)
-            g[r] = (t, offsets)
-            rank_rows.append((gkey, (func_id, tid, depth, masked, ret,
-                                     offsets, ret_masked)))
-        decoded.append(rank_rows)
-
-    # -- pass 2: fit rank-linear groups --------------------------------------
-    merged_offsets: Dict[Tuple[bytes, int], tuple] = {}
-    n_rank_patterns = 0
-    if inter_patterns and nranks > 1:
-        candidates: List[Tuple[Tuple[bytes, int], List[tuple]]] = []
-        for gkey in group_order:
-            g = groups[gkey]
-            if len(g) != nranks:
-                continue  # not present on every rank: no fit (paper: collective I/O case)
-            per_rank = [g[r][1] for r in range(nranks)]
-            if not per_rank[0]:
-                continue  # no offset args: identical signatures merge by interning
-            candidates.append((gkey, per_rank))
-        if fit_mode == "python":
-            fits = [_fit_offsets(pr) for _, pr in candidates]
-        else:
-            fits = _fit_offsets_batch(
-                [pr for _, pr in candidates],
-                backend="pallas" if fit_mode == "pallas" else None)
-        for (gkey, _), fit in zip(candidates, fits):
-            if fit is not None:
-                merged_offsets[gkey] = fit
-                if _fit_has_rank_pattern(fit):
-                    n_rank_patterns += 1
-
-    # -- pass 3: build merged table + per-rank remaps ------------------------
-    table: Dict[bytes, int] = {}
-    merged_entries: List[bytes] = []
-    remaps: List[Dict[int, int]] = [dict() for _ in range(nranks)]
-
-    def intern(sig: bytes) -> int:
-        t = table.get(sig)
-        if t is None:
-            t = len(merged_entries)
-            table[sig] = t
-            merged_entries.append(sig)
-        return t
-
-    for r, rank_rows in enumerate(decoded):
-        for old_t, (gkey, parts) in enumerate(rank_rows):
-            func_id, tid, depth, masked, ret, offsets, ret_masked = parts
-            fit = merged_offsets.get(gkey)
-            use_offsets = fit if fit is not None else offsets
-            it = iter(use_offsets)
-            args = tuple(next(it) if v is _MASK else v for v in masked)
-            if ret_masked:
-                ret = next(it)
-            sig = encode_signature(func_id, tid, depth, args, ret)
-            remaps[r][old_t] = intern(sig)
-
-    return MergeResult(merged_entries=merged_entries, remaps=remaps,
-                       n_rank_patterns=n_rank_patterns)
 
 
 def _fit_has_rank_pattern(fit: tuple) -> bool:
@@ -425,27 +133,8 @@ def dedupe_cfgs(rank_cfgs: List[bytes]) -> CfgResult:
     return CfgResult(unique_cfgs=unique, cfg_index=index)
 
 
-def finalize_ranks(rank_csts: List[List[bytes]], rank_cfgs: List[bytes],
-                   registry: FunctionRegistry, inter_patterns: bool = True,
-                   fit_mode: str = "vectorized"
-                   ) -> Tuple[MergeResult, CfgResult]:
-    """The full root-side FLAT finalization: merge CSTs, remap CFGs, dedupe.
-
-    This is the pure core shared by the SPMD path (``Recorder.finalize``
-    with ``finalize_topology="flat"``) and the simulated-rank drivers in
-    benchmarks/tests.  See :func:`tree_finalize_ranks` for the scalable
-    topology that produces byte-identical output.
-    """
-    merge = merge_csts(rank_csts, registry, inter_patterns=inter_patterns,
-                       fit_mode=fit_mode)
-    remapped = [remap_grammar(cfg, merge.remaps[r])
-                for r, cfg in enumerate(rank_cfgs)]
-    cfgs = dedupe_cfgs(remapped)
-    return merge, cfgs
-
-
 # ---------------------------------------------------------------------------
-# tree topology: incremental rank states
+# rank states
 # ---------------------------------------------------------------------------
 #
 # A RankState summarizes the CST/CFG of a *contiguous block* of ranks
@@ -457,8 +146,8 @@ def finalize_ranks(rank_csts: List[List[bytes]], rank_cfgs: List[bytes],
 #        in the local rank index.  O(1) per group regardless of block size.
 #   raw  explicit {global_rank: offsets} for groups whose linearity (or
 #        full presence) is broken.  This is the only part that can grow
-#        with the block size -- exactly the entries the flat merge would
-#        keep per-rank anyway.
+#        with the block size -- exactly the entries the merged CST keeps
+#        per rank anyway.
 #
 # Per-rank terminal streams (the CFG bytes plus the per-terminal group-key
 # sequence) are deduplicated inside the state, so N identical SPMD ranks
@@ -663,9 +352,9 @@ def _finalize_slot(slot: tuple) -> Any:
 def _final_fits(state: RankState) -> Dict[Tuple[bytes, int], tuple]:
     """Fits for every fully-present, still-linear group of the root state.
 
-    The heavy per-rank column fitting already happened incrementally
-    during the merges (each group carries an O(1) linear summary), so the
-    root only classifies slopes -- O(groups) regardless of fit mode.
+    The per-rank column fitting already happened incrementally during the
+    merges (each group carries an O(1) linear summary), so the root only
+    classifies slopes -- O(groups) regardless of the number of ranks.
     """
     nranks = state.n
     return {gkey: tuple(_finalize_slot(s) for s in g.lin)
@@ -688,33 +377,22 @@ def _values_for_rank(g: _Group, state: RankState, rank: int) -> tuple:
     return _lin_values(g.lin, rank - state.base)
 
 
-def materialize_state(state: RankState, inter_patterns: bool = True,
-                      fit_mode: str = "vectorized",
-                      cache_streams: bool = True
+def materialize_state(state: RankState, inter_patterns: bool = True
                       ) -> Tuple[MergeResult, CfgResult]:
     """Emit the merged CST + deduped CFGs from a fully-reduced state.
 
-    Byte-identical to :func:`finalize_ranks` on the same rank data: the
-    intern pass walks ranks in order and terminals in stream order, exactly
-    like the flat pass 3.  Streams whose groups all materialize to
-    rank-independent signatures are interned once and their remap reused,
-    which makes this O(unique streams + ranks) for SPMD workloads.
-    Near-uniform streams (a few rank-dependent rows in an otherwise
-    uniform stream) share the uniform rows' remap too: later ranks copy it
-    and re-sign only the irregular rows.  Both reuses preserve the flat
-    pass's intern order exactly -- a uniform row's intern at a later rank
-    is always a table hit, so skipping it cannot shift terminal ids
-    (property-tested cached vs uncached in ``tests/test_interprocess.py``).
-
-    ``cache_streams=False`` disables both reuses (every rank walks every
-    row) -- the reference path the property tests compare against.
-
-    ``fit_mode`` is accepted for API symmetry with :func:`finalize_ranks`
-    but does not change the work done here: tree fitting happens
-    incrementally during the merges, so materialization is
-    fit-mode-independent (the benchmark sweep reports both labels).
+    The intern pass walks ranks in order and terminals in stream order, so
+    terminal ids come out as a gather-at-root merge over the same rank
+    data would number them (``tests/_flat_merge_ref.py``).  Streams whose
+    groups all materialize to rank-independent signatures are interned
+    once and their remap reused, which makes this O(unique streams +
+    ranks) for SPMD workloads.  Near-uniform streams (a few rank-dependent
+    rows in an otherwise uniform stream) share the uniform rows' remap
+    too: later ranks copy it and re-sign only the irregular rows.  Neither
+    reuse shifts a terminal id -- a uniform row's intern at a later rank is
+    always a table hit (property-tested against the reference in
+    ``tests/test_tree_finalize.py``).
     """
-    del fit_mode
     nranks = state.n
     merged_offsets: Dict[Tuple[bytes, int], tuple] = {}
     n_rank_patterns = 0
@@ -766,13 +444,13 @@ def materialize_state(state: RankState, inter_patterns: bool = True,
     remapped_cfgs: List[bytes] = []
     for j in range(nranks):
         si = state.stream_of[j]
-        cached = stream_cache.get(si) if cache_streams else None
+        cached = stream_cache.get(si)
         if cached is not None:
             remaps.append(cached[0])
             remapped_cfgs.append(cached[1])
             continue
         cfg_bytes, rows = state.streams[si]
-        part = partial_cache.get(si) if cache_streams else None
+        part = partial_cache.get(si)
         if part is not None:
             base_remap, irr_rows = part
             remap = dict(base_remap)
@@ -808,19 +486,17 @@ def materialize_state(state: RankState, inter_patterns: bool = True,
 
 def tree_finalize_ranks(rank_csts: List[List[bytes]], rank_cfgs: List[bytes],
                         registry: FunctionRegistry,
-                        inter_patterns: bool = True,
-                        fit_mode: str = "vectorized"
+                        inter_patterns: bool = True
                         ) -> Tuple[MergeResult, CfgResult]:
-    """Tree-topology finalization over simulated rank lists.
+    """The inter-process pass over simulated rank lists.
 
     Builds one leaf state per rank, reduces pairwise in O(log N) rounds and
-    materializes -- byte-identical to :func:`finalize_ranks`.
+    materializes -- what rank 0 of a real run writes for the same ranks.
     """
     states = [make_rank_state(r, cst, cfg, registry)
               for r, (cst, cfg) in enumerate(zip(rank_csts, rank_cfgs))]
     root = tree_reduce_states(states)
-    return materialize_state(root, inter_patterns=inter_patterns,
-                             fit_mode=fit_mode)
+    return materialize_state(root, inter_patterns=inter_patterns)
 
 
 # ---------------------------------------------------------------------------

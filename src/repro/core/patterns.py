@@ -19,9 +19,9 @@ framework step counters compress with the same machinery.
 
 ``IntraPatternTracker.encode_many`` is the batched entry point: it encodes a
 whole sequence of calls for one key at once, finding arithmetic runs with
-the shared NumPy segmentation helper (``interprocess.arith_segments``) and
-is result- and state-equivalent to calling :meth:`encode` per call.  The
-benchmark drivers use it to synthesize large simulated-rank streams.
+the segmentation helper :func:`arith_segments`, and is result- and
+state-equivalent to calling :meth:`encode` per call.  The benchmark
+drivers use it to synthesize large simulated-rank streams.
 
 The record path keeps, in each run, the CST terminal of the signature
 that every call continuing the run shares (and the call depth it was
@@ -40,7 +40,6 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from .encoding import IterPattern
-from .interprocess import arith_segments
 
 
 @dataclass
@@ -190,6 +189,45 @@ class IntraPatternTracker:
                     self.runs[key] = _RunState(index=1, base=base,
                                                stride=None)
         return out
+
+
+def arith_segments(V: np.ndarray,
+                   backend: Optional[str] = None) -> List[Tuple[int, int]]:
+    """Greedy arithmetic-run segmentation of a (n, k) value matrix.
+
+    Returns half-open ``(start, end)`` element segments such that within a
+    segment every consecutive row difference equals the segment's first
+    difference (the run stride), mirroring the streaming protocol of
+    ``IntraPatternTracker``: a run's stride is set by its second element and
+    the run breaks at the first non-matching row.  ``backend`` dispatches
+    the change-point scan (``encode_backend.run_boundaries`` over the diff
+    rows); segmentation is identical across backends.
+    """
+    n = len(V)
+    if n == 0:
+        return []
+    if n == 1:
+        return [(0, 1)]
+    d = V[1:] - V[:-1]
+    if d.ndim == 1:
+        d = d[:, None]
+    # cp[j] for j >= 1: diff j differs from diff j-1
+    from . import encode_backend as _eb
+    mask = _eb.run_boundaries(d, backend)
+    mask[0] = False  # position 0 is forced True by the boundary op
+    cp = np.flatnonzero(mask)
+    segs: List[Tuple[int, int]] = []
+    s = 0
+    while s < n:
+        if s >= n - 1:
+            segs.append((s, n))
+            break
+        # largest run of equal diffs starting at diff index s
+        k = int(np.searchsorted(cp, s, side="right"))
+        c = int(cp[k]) if k < len(cp) else n - 1
+        segs.append((s, c + 1))
+        s = c + 1
+    return segs
 
 
 class IntraPatternDecoder:

@@ -38,9 +38,9 @@ import numpy as np
 from .comm import Comm, SoloComm
 from .cst import CST
 from .encoding import Handle
-from .interprocess import (deserialize_rank_state, finalize_ranks,
-                           make_rank_state, materialize_state,
-                           merge_serialized_states, serialize_rank_state)
+from .interprocess import (deserialize_rank_state, make_rank_state,
+                           materialize_state, merge_serialized_states,
+                           serialize_rank_state)
 from .patterns import IntraPatternTracker
 from .sequitur import Sequitur, concat_grammars
 from .specs import DATA_FUNCS, REGISTRY, FnSpec, FunctionRegistry, Role
@@ -96,11 +96,6 @@ class RecorderConfig:
     inter_patterns: bool = True              # paper §3.2.2 toggle (Fig 5)
     timestamps: bool = True
     store_buffers: bool = False              # record buffer lengths only
-    # "tree": hierarchical O(log N)-round reduction of serialized rank
-    # states (interprocess.merge_rank_states) through Comm.reduce_tree.
-    # "flat": the original gather-at-root pass, kept for bit-compat checks
-    # (both produce byte-identical traces; see tests/test_tree_finalize.py).
-    finalize_topology: str = "tree"
     # -- streaming (epoch flush) knobs; see core/streaming.py ----------------
     # auto-flush after this many locally recorded calls since the last flush
     flush_every_n_records: Optional[int] = None
@@ -128,13 +123,13 @@ class RecorderConfig:
     # ranks_present mask; a rank whose delta missed the commit keeps it
     # in memory for the next attempt (see streaming.run_flush_degraded)
     flush_timeout_s: Optional[float] = None
-    # backend for the batched encode/fit hot paths (timestamp delta+zigzag,
-    # varint packing, rank-linear fitting): "python" (scalar reference),
-    # "numpy" (vectorized host), "pallas" (device kernels; interpret-mode
-    # on CPU-only hosts), or "auto" (crossover by batch size -- numpy on
-    # CPU, kernels for large batches when an accelerator is attached).
-    # Every backend writes byte-identical traces
-    # (tests/test_encode_kernels.py); see core/encode_backend.py.
+    # backend for the batched encode hot paths (timestamp delta+zigzag,
+    # varint packing): "python" (scalar reference), "numpy" (vectorized
+    # host), "pallas" (device kernels; interpret-mode on CPU-only hosts),
+    # or "auto" (crossover by batch size -- numpy on CPU, kernels for large
+    # batches when an accelerator is attached).  Every backend writes
+    # byte-identical traces (tests/test_encode_kernels.py); see
+    # core/encode_backend.py.
     encode_backend: str = "auto"
 
     def __post_init__(self) -> None:
@@ -182,9 +177,6 @@ class RecorderConfig:
             cfg.intra_patterns = False
         if os.environ.get("RECORDER_NO_INTER_PATTERNS"):
             cfg.inter_patterns = False
-        topo = os.environ.get("RECORDER_FINALIZE_TOPOLOGY")
-        if topo:
-            cfg.finalize_topology = topo
         n = _env_int("RECORDER_FLUSH_EVERY_N_RECORDS")
         if n is not None:
             cfg.flush_every_n_records = n
@@ -204,14 +196,6 @@ class RecorderConfig:
         t = _env_float("RECORDER_FLUSH_TIMEOUT_S")
         if t is not None:
             cfg.flush_timeout_s = t
-        eb = os.environ.get("RECORDER_ENCODE_BACKEND")
-        if eb:
-            from .encode_backend import BACKENDS
-            if eb not in BACKENDS:
-                raise ValueError(
-                    f"RECORDER_ENCODE_BACKEND must be one of {BACKENDS}, "
-                    f"got {eb!r}")
-            cfg.encode_backend = eb
         return cfg
 
 
@@ -800,14 +784,11 @@ class Recorder:
         """Run the inter-process stage and write the trace (root returns
         stats; other ranks return None).
 
-        ``config.finalize_topology`` selects how rank states reach rank 0:
-        ``"tree"`` reduces serialized states pairwise through
-        ``comm.reduce_tree`` in O(log N) rounds (each hop merges two
-        contiguous rank blocks, so rank 0 only materializes the already
-        merged state); ``"flat"`` gathers every raw CST/CFG to rank 0 and
-        merges there.  Both write byte-identical traces; tree timestamps
-        travel as one concatenated payload per hop (``comm.gather_tree``),
-        bounding rank-0 fan-in, while flat keeps the reference gather.
+        Rank states reach rank 0 through ``comm.reduce_tree``: serialized
+        states are reduced pairwise in O(log N) rounds (each hop merges
+        two contiguous rank blocks, so rank 0 only materializes the
+        already merged state).  Timestamps travel as one concatenated
+        payload per hop (``comm.gather_tree``), bounding rank-0 fan-in.
 
         **Streaming runs** (any flush happened, or flush cadence knobs are
         set) finalize differently: the remaining tail is flushed as the
@@ -873,40 +854,19 @@ class Recorder:
     def _finalize_oneshot(self, comm: Comm, trace_dir: Optional[str]
                           ) -> Optional[RecorderStats]:
         self._finalized = True
-        if self.config.finalize_topology not in ("tree", "flat"):
-            raise ValueError(
-                f"finalize_topology must be 'tree' or 'flat', got "
-                f"{self.config.finalize_topology!r}")
         entries, cfg, ts = self.local_state()
-        if self.config.finalize_topology == "tree":
-            with selftrace.span("reduce"):
-                leaf = make_rank_state(comm.rank, entries, cfg, self.registry)
-                blob = comm.reduce_tree(serialize_rank_state(leaf),
-                                        merge_serialized_states)
-                rank_ts = comm.gather_tree(ts)
-            if comm.rank != 0:
-                comm.barrier()
-                return None
-            with selftrace.span("materialize"):
-                merge, cfgs = materialize_state(
-                    deserialize_rank_state(blob),
-                    inter_patterns=self.config.inter_patterns)
-        else:
-            with selftrace.span("reduce"):
-                gathered = comm.gather((entries, cfg, ts))
-            if comm.rank != 0:
-                comm.barrier()
-                return None
-            rank_csts = [g[0] for g in gathered]
-            rank_cfgs = [g[1] for g in gathered]
-            rank_ts = [g[2] for g in gathered]
-            with selftrace.span("materialize"):
-                merge, cfgs = finalize_ranks(
-                    rank_csts, rank_cfgs, self.registry,
-                    inter_patterns=self.config.inter_patterns,
-                    fit_mode=("pallas"
-                              if self.config.encode_backend == "pallas"
-                              else "vectorized"))
+        with selftrace.span("reduce"):
+            leaf = make_rank_state(comm.rank, entries, cfg, self.registry)
+            blob = comm.reduce_tree(serialize_rank_state(leaf),
+                                    merge_serialized_states)
+            rank_ts = comm.gather_tree(ts)
+        if comm.rank != 0:
+            comm.barrier()
+            return None
+        with selftrace.span("materialize"):
+            merge, cfgs = materialize_state(
+                deserialize_rank_state(blob),
+                inter_patterns=self.config.inter_patterns)
         stats = RecorderStats(
             n_records=self.n_records,
             n_skipped=self.n_skipped,

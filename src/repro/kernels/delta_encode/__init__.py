@@ -1,9 +1,7 @@
 from .ops import (
     delta_zigzag,
     delta_zigzag_varint,
-    fit_columns,
     uvarint_encode64,
 )
 
-__all__ = ["delta_zigzag", "delta_zigzag_varint", "fit_columns",
-           "uvarint_encode64"]
+__all__ = ["delta_zigzag", "delta_zigzag_varint", "uvarint_encode64"]

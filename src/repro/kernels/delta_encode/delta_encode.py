@@ -15,8 +15,6 @@ through the mod-2^32 decoder otherwise.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
@@ -171,56 +169,3 @@ def uvarint_encode64_pallas(lo: jax.Array, hi: jax.Array, *,
         interpret=interpret,
     )(lo_t, hi_t)
     return from_tiles(lens, n), from_tiles(planes, n)
-
-
-# ---------------------------------------------------------------------------
-# rank-linear column classification (interprocess.batch_fit_columns)
-# ---------------------------------------------------------------------------
-#
-# A column fits the rank-linear model iff its first-order deltas are
-# constant -- the same delta core as the timestamp kernel, batched over
-# column tiles.  flag: 0 = no fit, 1 = constant, 2 = linear (nonzero
-# slope); d0 = the first delta (the slope when linear).  The kernel sees
-# the matrix transposed, (ranks, columns), so columns fill the lanes and
-# the rank-to-rank delta is a roll along sublanes.
-
-
-def _fit_columns_kernel(v_ref, flag_ref, d0_ref, *, n_ranks: int):
-    v = v_ref[...]                                  # (ranks, blk) int32
-    d = v - pltpu.roll(v, 1, 0)                     # [r] = v[r] - v[r-1]
-    r = jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
-    valid = (r >= 1) & (r < n_ranks)
-    d0 = jnp.sum(jnp.where(r == 1, d, 0), axis=0, keepdims=True)
-    n_moves = jnp.sum(jnp.where(valid & (d != 0), 1, 0), axis=0,
-                      keepdims=True)
-    n_bends = jnp.sum(jnp.where(valid & (d != d0), 1, 0), axis=0,
-                      keepdims=True)
-    flag_ref[...] = jnp.where(n_moves == 0, 1,
-                              jnp.where((n_bends == 0) & (d0 != 0), 2, 0))
-    d0_ref[...] = d0
-
-
-def fit_columns_pallas(V: jax.Array, *, block: int = 128,
-                       interpret: bool = False):
-    """(C, R) int32 column matrix (R >= 2) -> per-column (flags, first
-    deltas) in one pallas_call over column tiles.  Deltas are int32, so
-    callers keep ``|V| < 2^30``."""
-    c, r = V.shape
-    blk = max(LANES, -(-block // LANES) * LANES)
-    cp = -(-c // blk) * blk
-    rp = -(-r // 8) * 8
-    T = jnp.pad(V.astype(jnp.int32).T, ((0, rp - r), (0, cp - c)))
-    col_spec = pl.BlockSpec((1, blk), lambda i: (0, i))
-    flags, d0 = pl.pallas_call(
-        functools.partial(_fit_columns_kernel, n_ranks=r),
-        grid=(cp // blk,),
-        in_specs=[pl.BlockSpec((rp, blk), lambda i: (0, i))],
-        out_specs=[col_spec, col_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((1, cp), jnp.int32),
-            jax.ShapeDtypeStruct((1, cp), jnp.int32),
-        ],
-        compiler_params=PARALLEL,
-        interpret=interpret,
-    )(T)
-    return flags[0, :c], d0[0, :c]

@@ -7,7 +7,6 @@ import jax
 from .delta_encode import (
     delta_zigzag_pallas,
     delta_zigzag_varint_pallas,
-    fit_columns_pallas,
     uvarint_encode64_pallas,
 )
 
@@ -34,9 +33,3 @@ def uvarint_encode64(lo, hi, *, block: int = 4096,
     planes) for the host varint scatter."""
     return uvarint_encode64_pallas(lo, hi, block=block, interpret=interpret)
 
-
-@partial(jax.jit, static_argnames=("block", "interpret"))
-def fit_columns(V, *, block: int = 128, interpret: bool = False):
-    """(C, R) int32 columns -> (flags, first deltas); flag 1 = constant,
-    2 = rank-linear, 0 = no fit."""
-    return fit_columns_pallas(V, block=block, interpret=interpret)

@@ -22,12 +22,3 @@ def uvarint_planes_ref(values: np.ndarray):
     from ...core.encode_backend import _uvarint_planes_np
     return _uvarint_planes_np(np.asarray(values, np.uint64))
 
-
-def fit_columns_ref(V: np.ndarray):
-    """(C, R) int columns -> (flags, first deltas) per the kernel's
-    encoding: 1 = constant, 2 = rank-linear, 0 = no fit."""
-    V = np.asarray(V, np.int64)
-    d = V[:, 1:] - V[:, :-1]
-    const = (d == 0).all(axis=1)
-    linear = (d == d[:, :1]).all(axis=1) & (d[:, 0] != 0)
-    return np.where(const, 1, np.where(linear, 2, 0)), d[:, 0]

@@ -3,7 +3,7 @@
 Three blocked passes over expanded symbol streams:
 
 - ``row_boundaries``: row-change mask of an (n, k) matrix, the shared core
-  of ``interprocess.arith_segments`` (over row diffs: a new arithmetic run
+  of ``patterns.arith_segments`` (over row diffs: a new arithmetic run
   starts where the diff row changes) and ``Sequitur.push_stream`` RLE
   pre-tokenization (over the raw terminal column: run starts).
 - ``histogram``: terminal occurrence counts via a one-hot compare against

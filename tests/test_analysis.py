@@ -12,7 +12,7 @@ from repro.core import trace_format
 from repro.core.analysis import (call_chains, consistency_pairs, io_summary,
                                  overlap_ratio, size_histogram)
 from repro.core.apis import posix, shardio
-from repro.core.interprocess import finalize_ranks
+from repro.core.interprocess import tree_finalize_ranks
 from repro.core.reader import TraceReader
 from repro.core.recorder import Recorder, RecorderConfig, session
 from repro.core.specs import REGISTRY
@@ -88,8 +88,8 @@ def _write_span_trace(tmp_path, spans):
         fdobj = object()
         rec.record(fid, (fdobj, b"a" * size, off), size, 0, 0, 1)
         states.append(rec.local_state())
-    merge, cfgs = finalize_ranks([s[0] for s in states],
-                                 [s[1] for s in states], REGISTRY)
+    merge, cfgs = tree_finalize_ranks([s[0] for s in states],
+                                      [s[1] for s in states], REGISTRY)
     tdir = str(tmp_path / "trace")
     trace_format.write_trace(tdir, registry=REGISTRY,
                              merged_cst=merge.merged_entries,

@@ -28,7 +28,6 @@ KERNELS = {
                             lambda n: [((n,), jnp.uint32)]),
     "uvarint_encode64": (de.uvarint_encode64,
                          lambda n: [((n,), jnp.uint32)] * 2),
-    "fit_columns": (de.fit_columns, lambda n: [((n // 64, 64), jnp.int32)]),
     "row_boundaries": (gs.row_boundaries, lambda n: [((n, 3), jnp.int32)]),
     "histogram": (lambda s: gs.histogram(s, 5000),
                   lambda n: [((n,), jnp.int32)]),

@@ -37,7 +37,7 @@ from benchmarks.workloads import synth_rank_states
 from repro.core import dfg, faults, trace_format
 from repro.core.comm import run_thread_world
 from repro.core.faults import FaultPlan
-from repro.core.interprocess import finalize_ranks, tree_finalize_ranks
+from repro.core.interprocess import tree_finalize_ranks
 from repro.core.reader import TraceReader
 from repro.core.recorder import Recorder, RecorderConfig
 from repro.core.sequitur import (Sequitur, concat_grammars, expand_grammar,
@@ -86,14 +86,15 @@ def _feed(rec, calls, tick_start=0):
 
 
 def _write_plain_trace(d, rank_calls):
-    """Per-rank Recorder -> finalize_ranks -> one plain trace dir at ``d``."""
+    """Per-rank Recorder -> tree_finalize_ranks -> one plain trace dir at
+    ``d``."""
     states = []
     for r, calls in enumerate(rank_calls):
         rec = Recorder(rank=r, config=RecorderConfig())
         _feed(rec, calls)
         states.append(rec.local_state())
-    merge, cfgs = finalize_ranks([s[0] for s in states],
-                                 [s[1] for s in states], REGISTRY)
+    merge, cfgs = tree_finalize_ranks([s[0] for s in states],
+                                      [s[1] for s in states], REGISTRY)
     trace_format.write_trace(d, registry=REGISTRY,
                              merged_cst=merge.merged_entries,
                              unique_cfgs=cfgs.unique_cfgs,

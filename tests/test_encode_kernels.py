@@ -5,7 +5,7 @@ interpret mode so the suite executes on CPU-only CI) must be BYTE-identical
 to the scalar Python path: the backend knob may never change what lands in
 a trace file.  Properties cover randomized tick streams (wraps, zero
 deltas, max-u32), ragged varint length classes, empty blocks, the u64
-batch guard, the rank-linear fit/segmentation dispatchers, the
+batch guard, the run-segmentation dispatcher, the
 grammar-stats kernels, and full Recorder round-trips through TraceReader
 under every backend.
 """
@@ -30,9 +30,8 @@ from repro.core.apis import posix
 from repro.core.encoding import (VarintRangeError, decode_value,
                                  encode_value, pack_uvarints, read_uvarint,
                                  write_uvarint)
-from repro.core.interprocess import (arith_segments, batch_fit_columns,
-                                     finalize_ranks)
-from repro.core.patterns import IntraPatternTracker
+from repro.core.interprocess import tree_finalize_ranks
+from repro.core.patterns import IntraPatternTracker, arith_segments
 from repro.core.reader import TraceReader
 from repro.core.recorder import Recorder, RecorderConfig, attach, detach
 from repro.core.sequitur import Sequitur, expand_grammar, parse_grammar
@@ -175,41 +174,8 @@ def test_scalar_writers_stay_arbitrary_precision():
 
 
 # ---------------------------------------------------------------------------
-# rank-linear fitting + run segmentation dispatchers
+# run segmentation dispatchers
 # ---------------------------------------------------------------------------
-
-def _columns(n_cols, n_ranks):
-    cols = []
-    for _ in range(n_cols):
-        kind = rng.randint(0, 3)
-        if kind == 0:
-            cols.append([int(rng.randint(-50, 50))] * n_ranks)
-        elif kind == 1:
-            a, b = int(rng.randint(-9, 9)) or 3, int(rng.randint(-99, 99))
-            cols.append([b + r * a for r in range(n_ranks)])
-        else:
-            cols.append([int(v) for v in rng.randint(-1000, 1000,
-                                                     size=n_ranks)])
-    return cols
-
-
-@pytest.mark.parametrize("n_cols,n_ranks", [(1, 2), (40, 8), (300, 16)])
-def test_batch_fit_columns_backends_identical(n_cols, n_ranks):
-    cols = _columns(n_cols, n_ranks)
-    ref = batch_fit_columns(cols, backend="python")
-    for b in BATCH:
-        assert batch_fit_columns(cols, backend=b) == ref, b
-
-
-def test_fit_classify_values_whose_int32_deltas_wrap():
-    big = (1 << 31) - 1
-    V = np.array([[-big, big], [0, 1 << 30], [5, 5], [1 << 30, -(1 << 30)]],
-                 np.int64)
-    want = eb.fit_classify(V, "numpy")
-    got = eb.fit_classify(V, "pallas")
-    for w, g in zip(want, got):
-        np.testing.assert_array_equal(g, w)
-
 
 @pytest.mark.parametrize("k", [1, 2, 3])
 def test_arith_segments_backends_identical(k):
@@ -404,8 +370,8 @@ def _spmd_trace(base, nranks=3, n=120):
         finally:
             detach()
         states.append(rec.local_state())
-    merge, cfgs = finalize_ranks([s[0] for s in states],
-                                 [s[1] for s in states], REGISTRY)
+    merge, cfgs = tree_finalize_ranks([s[0] for s in states],
+                                      [s[1] for s in states], REGISTRY)
     tdir = os.path.join(base, "trace")
     trace_format.write_trace(
         tdir, registry=REGISTRY, merged_cst=merge.merged_entries,

@@ -12,7 +12,7 @@ sys.path.insert(0, "/root/repo")
 from benchmarks.workloads import ior_rank  # noqa: E402
 from repro.core import trace_format
 from repro.core.comm import run_thread_world
-from repro.core.interprocess import finalize_ranks
+from repro.core.interprocess import tree_finalize_ranks
 from repro.core.reader import TraceReader
 from repro.core.recorder import Recorder, RecorderConfig
 from repro.core.specs import REGISTRY
@@ -25,8 +25,8 @@ def _write_multirank_trace(tmp_path, nprocs, n_calls, chunk=512):
         rec = Recorder(rank=r, config=RecorderConfig())
         ior_rank(rec, r, nprocs, n_calls, chunk=chunk, data_dir=data_dir)
         states.append(rec.local_state())
-    merge, cfgs = finalize_ranks([s[0] for s in states],
-                                 [s[1] for s in states], REGISTRY)
+    merge, cfgs = tree_finalize_ranks([s[0] for s in states],
+                                      [s[1] for s in states], REGISTRY)
     trace_dir = str(tmp_path / "trace")
     trace_format.write_trace(
         trace_dir, registry=REGISTRY, merged_cst=merge.merged_entries,

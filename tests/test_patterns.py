@@ -8,8 +8,8 @@ try:
 except ImportError:  # fallback: seeded-random example generation
     from _hypothesis_compat import given, settings, strategies as st
 
-from repro.core.encoding import IterPattern, RankPattern
-from repro.core.interprocess import _fit_component, merge_csts, dedupe_cfgs
+from repro.core.encoding import IterPattern, RankPattern, decode_signature
+from repro.core.interprocess import dedupe_cfgs, tree_finalize_ranks
 from repro.core.patterns import IntraPatternDecoder, IntraPatternTracker
 from repro.core.specs import REGISTRY
 import repro.core.apis  # noqa: F401  (populate registry)
@@ -47,11 +47,21 @@ def test_multi_offset_joint_run():
         assert dec.decode("k", e) == [i * 4, i * 100 + 7]
 
 
+def _offsets_by_rank(column):
+    """Rank r writes at ``column[r]``; the offset each rank's call carries
+    in the merged CST."""
+    fid = REGISTRY.id_of("pwrite")
+    csts = [[_sig(fid, (None, 64, v))] for v in column]
+    merged = _merge(csts)
+    return [decode_signature(merged.merged_entries[m[0]])[3][2]
+            for m in merged.remaps]
+
+
 def test_fit_component():
-    assert _fit_component([5, 5, 5]) == 5
-    assert _fit_component([3, 7, 11, 15]) == RankPattern(4, 3)
-    assert _fit_component([3, 7, 12]) is None
-    assert _fit_component([1]) == 1
+    assert _offsets_by_rank([5, 5, 5]) == [5] * 3
+    assert _offsets_by_rank([3, 7, 11, 15]) == [RankPattern(4, 3)] * 4
+    assert _offsets_by_rank([3, 7, 12]) == [3, 7, 12]
+    assert _offsets_by_rank([1]) == [1]
 
 
 def test_dedupe_cfgs():
@@ -65,12 +75,27 @@ def _sig(fid, args, ret=0):
     return encode_signature(fid, 0, 0, args, ret)
 
 
+def _merge(csts, inter_patterns=True):
+    """The inter-process pass over per-rank CSTs; each rank's grammar
+    calls its terminals once, in order."""
+    from repro.core.sequitur import Sequitur
+    cfgs = []
+    for cst in csts:
+        g = Sequitur()
+        for t in range(len(cst)):
+            g.push(t)
+        cfgs.append(g.serialize())
+    merged, _ = tree_finalize_ranks(csts, cfgs, REGISTRY,
+                                    inter_patterns=inter_patterns)
+    return merged
+
+
 def test_merge_rank_linear():
     """Paper Fig 3(c): per-rank offsets rank*a+b merge to one entry."""
     fid = REGISTRY.id_of("pwrite")
     nranks = 4
     csts = [[_sig(fid, (None, 64, r * 100))] for r in range(nranks)]
-    merged = merge_csts(csts, REGISTRY)
+    merged = _merge(csts)
     assert len(merged.merged_entries) == 1
     assert merged.n_rank_patterns == 1
     # every rank remaps its terminal 0 to merged terminal 0
@@ -83,7 +108,7 @@ def test_merge_respects_occurrence_index():
     fid = REGISTRY.id_of("pwrite")
     csts = [[_sig(fid, (None, 64, r * 10)), _sig(fid, (None, 64, 5000 + r * 10))]
             for r in range(3)]
-    merged = merge_csts(csts, REGISTRY)
+    merged = _merge(csts)
     assert len(merged.merged_entries) == 2
 
 
@@ -92,12 +117,12 @@ def test_merge_partial_rank_group_not_fitted():
     per-rank rather than wrongly merged."""
     fid = REGISTRY.id_of("pwrite")
     csts = [[_sig(fid, (None, 64, 0))], [_sig(fid, (None, 64, 100))], []]
-    merged = merge_csts(csts, REGISTRY)
+    merged = _merge(csts)
     assert len(merged.merged_entries) == 2  # no fit without full coverage
 
 
 def test_merge_no_inter_flag():
     fid = REGISTRY.id_of("pwrite")
     csts = [[_sig(fid, (None, 64, r * 100))] for r in range(4)]
-    merged = merge_csts(csts, REGISTRY, inter_patterns=False)
+    merged = _merge(csts, inter_patterns=False)
     assert len(merged.merged_entries) == 4

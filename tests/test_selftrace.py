@@ -230,27 +230,52 @@ def test_threadcomm_ranks_fold_their_own_sums(traced, tmp_path):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("topology", ["tree", "flat"])
-def test_finalize_span_tree(traced, tmp_path, topology):
-    rec = Recorder(config=RecorderConfig(trace_dir=str(tmp_path / "t"),
-                                         finalize_topology=topology,
-                                         encode_backend="numpy"))
-    _pwrites(tmp_path, rec, 100)
-    rec.finalize()
+def _finalize_world(tmp_path, nranks, n=100):
+    """``nranks`` recorders (ThreadComm ranks on threads when more than
+    one) each record ``n + 2`` calls and finalize one trace; returns rank
+    0's thread name and the records of all ranks."""
+    config = RecorderConfig(trace_dir=str(tmp_path / "t"),
+                            encode_backend="numpy")
+    if nranks == 1:
+        rec = Recorder(config=config)
+        _pwrites(tmp_path, rec, n)
+        rec.finalize()
+        return threading.current_thread().name, rec.n_records
+    fid = REGISTRY.id_of("pwrite")
+
+    def worker(comm, rank):
+        rec = Recorder(rank=rank, config=config)
+        fd = object()
+        for i in range(n + 2):
+            rec.record(fid, (fd, b"x" * 64, (i * nranks + rank) * 64), 64,
+                       0, 2 * i, 2 * i + 1)
+        rec.finalize(comm)
+        return threading.current_thread().name, rec.n_records
+
+    out = run_thread_world(nranks, worker)
+    return out[0][0], sum(o[1] for o in out)
+
+
+@pytest.mark.parametrize("nranks", [pytest.param(1, id="tree"),
+                                    pytest.param(3, id="threadcomm3")])
+def test_finalize_span_tree(traced, tmp_path, nranks):
+    root_thread, n_records = _finalize_world(tmp_path, nranks)
     snap = traced.snapshot()
     by = _by_name(snap)
-    (fin,) = by["finalize"]
+    fins = by["finalize"]
+    assert len(fins) == nranks
+    (fin,) = [f for f in fins if f["thread"] == root_thread]
     assert fin["parent"] is None
     assert _children(snap, fin) == ["encode", "materialize", "reduce",
                                     "write"]
-    (enc,) = by["encode"]
-    assert _children(snap, enc) == ["encode.delta_zigzag"]
+    for enc in by["encode"]:
+        assert _children(snap, enc) == ["encode.delta_zigzag"]
     for s in snap["spans"]:
-        if s is not fin:
+        if s["thread"] == root_thread and s is not fin:
             assert fin["start_ns"] <= s["start_ns"] <= s["end_ns"] \
                 <= fin["end_ns"]
     assert snap["counters"]["encode.delta_zigzag.numpy.elements"] == \
-        2 * rec.n_records               # entry and exit ticks per record
+        2 * n_records                   # entry and exit ticks per record
 
 
 @pytest.mark.parametrize("async_flush", [False, True])
@@ -345,15 +370,10 @@ def test_encode_counters_name_what_ran(traced):
     eb.delta_zigzag(np.arange(10), "auto")
     eb.delta_zigzag(np.arange(eb.NUMPY_MIN_BATCH), "auto")
     eb.run_boundaries(np.array([[1 << 40], [0]]), "pallas")
-    eb.fit_classify(np.array([[0, 1, 2], [5, 5, 5]]), "pallas")
-    eb.fit_classify(np.array([[0, 1 << 40]]), "pallas")
     c = traced.snapshot()["counters"]
     assert c["encode.delta_zigzag.python.calls"] == 1
     assert c["encode.delta_zigzag.numpy.calls"] == 1
     assert c["encode.run_boundaries.numpy.calls"] == 1
-    assert c["encode.fit_classify.pallas.calls"] == 1
-    assert c["encode.fit_classify.pallas.elements"] == 6
-    assert c["encode.fit_classify.numpy.calls"] == 1
     assert not any(k.startswith("encode.run_boundaries.pallas") for k in c)
 
 

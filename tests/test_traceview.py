@@ -18,7 +18,7 @@ from repro.core import analysis, trace_format
 from repro.core.encoding import (Handle, IterPattern, RankPattern,
                                  decode_signature, decode_signatures_batch,
                                  encode_signature)
-from repro.core.interprocess import finalize_ranks
+from repro.core.interprocess import tree_finalize_ranks
 from repro.core.reader import TraceReader
 from repro.core.recorder import Recorder, RecorderConfig
 from repro.core.sequitur import (Sequitur, expand_grammar,
@@ -198,8 +198,8 @@ def _build_random_trace(tmp, seed):
         rec = Recorder(rank=r, config=RecorderConfig())
         _run_plan(rec, ops, r, nprocs, random.Random(seed * 1009 + r))
         states.append(rec.local_state())
-    merge, cfgs = finalize_ranks([s[0] for s in states],
-                                 [s[1] for s in states], REGISTRY)
+    merge, cfgs = tree_finalize_ranks([s[0] for s in states],
+                                      [s[1] for s in states], REGISTRY)
     d = f"{tmp}/trace"
     trace_format.write_trace(d, registry=REGISTRY,
                              merged_cst=merge.merged_entries,
@@ -274,8 +274,8 @@ def test_per_file_fallback_on_handle_reuse(tmp_path):
         rec.record(fid("close"), (f2,), 0, 0, 5, 6)
         rec.forget_handle(f2)
         states.append(rec.local_state())
-    merge, cfgs = finalize_ranks([s[0] for s in states],
-                                 [s[1] for s in states], REGISTRY)
+    merge, cfgs = tree_finalize_ranks([s[0] for s in states],
+                                      [s[1] for s in states], REGISTRY)
     d = str(tmp_path / "t")
     trace_format.write_trace(d, registry=REGISTRY,
                              merged_cst=merge.merged_entries,

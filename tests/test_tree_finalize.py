@@ -1,6 +1,7 @@
-"""Tree-reduction finalize: byte-identity with the flat pass, state
-serialization, the ThreadComm collective path, reader round-trips, and the
-vectorized fitting / batched intra-pattern encoding equivalences."""
+"""Tree-reduction finalize: byte-identity with the gather-at-root
+reference merge (``_flat_merge_ref``), state serialization, the ThreadComm
+collective path, reader round-trips, and the batched intra-pattern
+encoding equivalences."""
 
 import os
 import random
@@ -12,21 +13,24 @@ try:
 except ImportError:  # fallback: seeded-random example generation
     from _hypothesis_compat import given, settings, strategies as st
 
+from _flat_merge_ref import finalize_ranks, fit_component
 from benchmarks.workloads import synth_rank_states
 from repro.core import trace_format
 from repro.core.comm import Comm, SoloComm, run_thread_world
-from repro.core.interprocess import (batch_fit_columns, deserialize_rank_state,
-                                     finalize_ranks, make_rank_state,
+from repro.core.encoding import IterPattern, decode_signature, encode_signature
+from repro.core.interprocess import (deserialize_rank_state, make_rank_state,
                                      materialize_state, merge_rank_states,
                                      merge_serialized_states,
                                      serialize_rank_state,
-                                     tree_finalize_ranks, tree_reduce_states,
-                                     _fit_component)
+                                     tree_finalize_ranks, tree_reduce_states)
 from repro.core.patterns import IntraPatternTracker
 from repro.core.reader import TraceReader
 from repro.core.recorder import Recorder, RecorderConfig
+from repro.core.sequitur import Sequitur
 from repro.core.specs import REGISTRY
 import repro.core.apis  # noqa: F401  (populate registry)
+
+PWRITE = REGISTRY.id_of("pwrite")
 
 
 def _assert_same_finalize(r1, r2):
@@ -40,7 +44,7 @@ def _assert_same_finalize(r1, r2):
 
 
 # ---------------------------------------------------------------------------
-# flat <-> tree byte-identity (the tentpole property)
+# tree <-> reference byte-identity
 # ---------------------------------------------------------------------------
 
 
@@ -49,20 +53,15 @@ def _assert_same_finalize(r1, r2):
        st.sampled_from(["linear", "constant", "irregular", "mixed"]),
        st.integers(1, 6), st.integers(1, 8), st.integers(0, 2 ** 20))
 def test_tree_matches_flat_bytes(nranks, pattern, n_groups, n_calls, seed):
-    """tree_finalize_ranks output is identical to flat finalize_ranks for
+    """tree_finalize_ranks output is identical to the reference merge for
     randomized rank counts (incl. non-powers-of-two) and offset patterns."""
     csts, cfgs = synth_rank_states(nranks, n_groups=n_groups,
                                    n_calls=n_calls, pattern=pattern,
                                    seed=seed)
     for inter in (True, False):
-        flat = finalize_ranks(csts, cfgs, REGISTRY, inter_patterns=inter,
-                              fit_mode="python")
         _assert_same_finalize(
-            flat, finalize_ranks(csts, cfgs, REGISTRY, inter_patterns=inter,
-                                 fit_mode="vectorized"))
-        _assert_same_finalize(
-            flat, tree_finalize_ranks(csts, cfgs, REGISTRY,
-                                      inter_patterns=inter))
+            finalize_ranks(csts, cfgs, REGISTRY, inter_patterns=inter),
+            tree_finalize_ranks(csts, cfgs, REGISTRY, inter_patterns=inter))
 
 
 @settings(max_examples=20, deadline=None)
@@ -76,7 +75,6 @@ def test_tree_matches_flat_with_partial_groups(nranks, n_groups, seed):
     csts = [list(c) for c in csts]
     cfgs = list(cfgs)
     # drop a suffix of terminals on a few ranks (and shrink their grammar)
-    from repro.core.sequitur import Sequitur
     for r in rng.sample(range(nranks), max(1, nranks // 4)):
         keep = rng.randrange(0, len(csts[r]))
         csts[r] = csts[r][:keep]
@@ -112,13 +110,86 @@ def test_tree_matches_flat_nested_and_multi_offset(nranks, pattern, n_groups,
                                                    n_calls, seed):
     """The extended synth shapes -- nested IterPattern-of-RankPattern
     offsets (paper Fig 3c) and joint multi-offset lseek runs -- finalize
-    identically on both topologies."""
+    as the reference merges them."""
     csts, cfgs = synth_rank_states(nranks, n_groups=n_groups,
                                    n_calls=n_calls, pattern=pattern,
                                    seed=seed)
     _assert_same_finalize(
-        finalize_ranks(csts, cfgs, REGISTRY, fit_mode="python"),
+        finalize_ranks(csts, cfgs, REGISTRY),
         tree_finalize_ranks(csts, cfgs, REGISTRY))
+
+
+def _column_ranks(columns, nranks):
+    """Rank CSTs in which column ``k`` is one pwrite group: rank ``r``
+    writes at ``columns[k][r]``, with a size of its own per group.  A
+    column of ``IterPattern``s is an intra-rank run whose stride and base
+    both vary over the ranks."""
+    csts = [[encode_signature(PWRITE, 0, 0, (None, 64 + k, col[r]), 64 + k)
+             for k, col in enumerate(columns)] for r in range(nranks)]
+    g = Sequitur()
+    for t in range(len(columns)):
+        g.push(t, 1 + t % 3)
+    return csts, [g.serialize()] * nranks
+
+
+@pytest.mark.parametrize("nranks", [2, 3, 8])
+def test_tree_matches_reference_bigint_offsets(nranks):
+    """Offsets beyond int64 (every leaf near 2^70) fit and merge as the
+    reference merges them."""
+    big = 1 << 70
+    columns = [
+        [big + 5 * r for r in range(nranks)],               # rank-linear
+        [big + 7] * nranks,                                 # constant
+        [big + r * r for r in range(nranks)],               # broken past 2
+        [-big - (1 << 66) * r for r in range(nranks)],      # negative slope
+        [IterPattern(big + 3 * r, big - r) for r in range(nranks)],
+    ]
+    csts, cfgs = _column_ranks(columns, nranks)
+    tree = tree_finalize_ranks(csts, cfgs, REGISTRY)
+    _assert_same_finalize(finalize_ranks(csts, cfgs, REGISTRY), tree)
+    assert tree[0].n_rank_patterns >= 3
+
+
+# (rank 0, rank 1) values whose difference wraps in int32 arithmetic
+_WRAP = [(-((1 << 31) - 1), (1 << 31) - 1), (0, 1 << 30), (5, 5),
+         (1 << 30, -(1 << 30))]
+
+
+@pytest.mark.parametrize("nranks", [2, 3, 8])
+def test_tree_matches_reference_int32_wrapping_offsets(nranks):
+    """Columns whose rank-to-rank deltas overflow int32, laid out both
+    rank-linear and with the last rank off the line, fit and merge as the
+    reference merges them."""
+    columns = []
+    for v0, v1 in _WRAP:
+        line = [v0 + r * (v1 - v0) for r in range(nranks)]
+        columns.append(line)
+        columns.append(line[:-1] + [line[-1] + (1 << 31)])
+        columns.append([IterPattern(line[r], v0) for r in range(nranks)])
+    csts, cfgs = _column_ranks(columns, nranks)
+    tree = tree_finalize_ranks(csts, cfgs, REGISTRY)
+    _assert_same_finalize(finalize_ranks(csts, cfgs, REGISTRY), tree)
+    assert tree[0].n_rank_patterns >= 6
+
+
+@settings(max_examples=60, deadline=None)
+@given(st.lists(st.lists(st.integers(-2 ** 40, 2 ** 40), min_size=3,
+                         max_size=6), min_size=1, max_size=8))
+def test_tree_fits_match_reference_fitter(cols):
+    """Each column, one group over the ranks, merges to the signature the
+    reference's scalar fitter gives it: one shared entry holding the
+    constant or the ``RankPattern``, or per-rank entries when the column
+    is not rank-linear."""
+    cols = [c for c in cols if len(c) == len(cols[0])]
+    nranks = len(cols[0])
+    csts, cfgs = _column_ranks(cols, nranks)
+    merge, _ = tree_finalize_ranks(csts, cfgs, REGISTRY)
+    for k, col in enumerate(cols):
+        fit = fit_component(col)
+        for r in range(nranks):
+            sig = merge.merged_entries[merge.remaps[r][k]]
+            offset = decode_signature(sig)[3][2]
+            assert offset == (col[r] if fit is None else fit), (k, r)
 
 
 def test_synth_nested_roundtrips_through_reader(tmp_path):
@@ -210,23 +281,26 @@ def test_reduce_tree_solo_and_generic():
     assert ListComm().reduce_tree("x", lambda a, b: a + b) == "x" * 15
 
 
-def _run_threaded(tmp_path, topology, nprocs=5, n_calls=24, chunk=512):
-    """N ranks on N threads; records are fed directly (the wrapper slot is
-    a process-global, shared across threads) and finalize runs through the
-    real ThreadComm collectives with the requested topology."""
-    trace_dir = str(tmp_path / f"trace_{topology}")
+def _feed(rec, rank, nprocs, n_calls, chunk):
+    """One rank's strided lseek+write loop, fed to ``rec.record``."""
     fid_seek = REGISTRY.id_of("lseek")
     fid_write = REGISTRY.id_of("write")
+    fd = object()
+    for i in range(n_calls):
+        off = rank * chunk + i * nprocs * chunk
+        rec.record(fid_seek, (fd, off, 0), off, 0, 2 * i, 2 * i + 1)
+        rec.record(fid_write, (fd, b"x" * 64), 64, 0, 2 * i + 1, 2 * i + 2)
+
+
+def _run_threaded(tmp_path, nprocs=5, n_calls=24, chunk=512):
+    """N ranks on N threads; records are fed directly (the wrapper slot is
+    a process-global, shared across threads) and finalize runs through the
+    real ThreadComm collectives."""
+    trace_dir = str(tmp_path / "trace")
 
     def worker(comm, rank):
-        rec = Recorder(rank=rank, config=RecorderConfig(
-            finalize_topology=topology))
-        fd = object()
-        for i in range(n_calls):
-            off = rank * chunk + i * nprocs * chunk
-            rec.record(fid_seek, (fd, off, 0), off, 0, 2 * i, 2 * i + 1)
-            rec.record(fid_write, (fd, b"x" * 64), 64, 0, 2 * i + 1,
-                       2 * i + 2)
+        rec = Recorder(rank=rank, config=RecorderConfig())
+        _feed(rec, rank, nprocs, n_calls, chunk)
         return rec.finalize(comm, trace_dir=trace_dir)
 
     stats = run_thread_world(nprocs, worker)
@@ -235,20 +309,37 @@ def _run_threaded(tmp_path, topology, nprocs=5, n_calls=24, chunk=512):
     return trace_dir
 
 
-def test_threadcomm_tree_trace_matches_flat(tmp_path):
-    """Concurrent tree finalize over ThreadComm writes byte-identical trace
-    files to the flat gather path."""
-    d_tree = _run_threaded(tmp_path, "tree")
-    d_flat = _run_threaded(tmp_path, "flat")
-    for name in ("merged_cst.bin", "unique_cfgs.bin", "cfg_index.bin"):
+@pytest.mark.parametrize("nprocs", [1, 5, 8])
+def test_threadcomm_tree_trace_matches_reference(tmp_path, nprocs):
+    """A concurrent finalize over ThreadComm writes the trace files that
+    the reference merge writes from the same ranks' local states."""
+    n_calls, chunk = 24, 512
+    d_tree = _run_threaded(tmp_path, nprocs=nprocs, n_calls=n_calls,
+                           chunk=chunk)
+    states = []
+    for r in range(nprocs):
+        rec = Recorder(rank=r, config=RecorderConfig())
+        _feed(rec, r, nprocs, n_calls, chunk)
+        states.append(rec.local_state())
+    merge, cfgs = finalize_ranks([s[0] for s in states],
+                                 [s[1] for s in states], REGISTRY)
+    d_ref = str(tmp_path / "reference")
+    trace_format.write_trace(d_ref, registry=REGISTRY,
+                             merged_cst=merge.merged_entries,
+                             unique_cfgs=cfgs.unique_cfgs,
+                             cfg_index=cfgs.cfg_index,
+                             rank_timestamps=[s[2] for s in states],
+                             meta_extra={})
+    for name in ("merged_cst.bin", "unique_cfgs.bin", "cfg_index.bin",
+                 "timestamps.bin"):
         with open(os.path.join(d_tree, name), "rb") as f1, \
-                open(os.path.join(d_flat, name), "rb") as f2:
+                open(os.path.join(d_ref, name), "rb") as f2:
             assert f1.read() == f2.read(), name
 
 
 def test_threadcomm_tree_nonpow2(tmp_path):
     for nprocs in (3, 6, 7):
-        d = _run_threaded(tmp_path / str(nprocs), "tree", nprocs=nprocs)
+        d = _run_threaded(tmp_path / str(nprocs), nprocs=nprocs)
         r = TraceReader(d)
         assert r.nranks == nprocs
 
@@ -257,8 +348,7 @@ def test_reader_roundtrip_tree_finalized(tmp_path):
     """TraceReader reconstructs every rank's exact offsets from a trace
     finalized through the tree topology."""
     nprocs, n_calls, chunk = 6, 30, 512
-    d = _run_threaded(tmp_path, "tree", nprocs=nprocs, n_calls=n_calls,
-                      chunk=chunk)
+    d = _run_threaded(tmp_path, nprocs=nprocs, n_calls=n_calls, chunk=chunk)
     reader = TraceReader(d)
     assert reader.nranks == nprocs
     assert len(reader.unique_cfgs) == 1    # identical SPMD ranks deduped
@@ -269,29 +359,9 @@ def test_reader_roundtrip_tree_finalized(tmp_path):
                         for i in range(n_calls)]
 
 
-def test_recorder_env_topology(monkeypatch):
-    monkeypatch.setenv("RECORDER_FINALIZE_TOPOLOGY", "flat")
-    assert RecorderConfig.from_env().finalize_topology == "flat"
-    monkeypatch.delenv("RECORDER_FINALIZE_TOPOLOGY")
-    assert RecorderConfig.from_env().finalize_topology == "tree"
-
-
 # ---------------------------------------------------------------------------
-# vectorized fitting / batched intra-pattern encoding equivalences
+# batched intra-pattern encoding equivalences
 # ---------------------------------------------------------------------------
-
-
-@settings(max_examples=60, deadline=None)
-@given(st.lists(st.lists(st.integers(-2 ** 40, 2 ** 40), min_size=3,
-                         max_size=6), max_size=8))
-def test_batch_fit_matches_scalar(cols):
-    cols = [c for c in cols if len(c) == len(cols[0])] if cols else []
-    assert batch_fit_columns(cols) == [_fit_component(c) for c in cols]
-
-
-def test_batch_fit_bigint_fallback():
-    cols = [[1 << 70, (1 << 70) + 5, (1 << 70) + 10], [7, 7, 7]]
-    assert batch_fit_columns(cols) == [_fit_component(c) for c in cols]
 
 
 @settings(max_examples=60, deadline=None)
@@ -361,19 +431,13 @@ def test_materialize_stream_cache_matches_uncached(nranks, pattern, n_groups,
                                                    n_calls, seed):
     """Near-uniform remap-stream reuse (uniform prefix shared, only the
     irregular rows re-interned per rank) is byte-identical to the
-    cache-disabled reference walk AND to the flat finalize."""
+    reference merge, which walks every row of every rank."""
     csts, cfgs = synth_rank_states(nranks, n_groups=n_groups,
                                    n_calls=n_calls, pattern=pattern,
                                    seed=seed)
     state = tree_reduce_states([make_rank_state(r, csts[r], cfgs[r], REGISTRY)
                                 for r in range(nranks)])
     for inter in (True, False):
-        cached = materialize_state(state, inter_patterns=inter,
-                                   cache_streams=True)
         _assert_same_finalize(
-            cached,
-            materialize_state(state, inter_patterns=inter,
-                              cache_streams=False))
-        _assert_same_finalize(
-            cached,
+            materialize_state(state, inter_patterns=inter),
             finalize_ranks(csts, cfgs, REGISTRY, inter_patterns=inter))
