@@ -251,6 +251,9 @@ class Recorder:
         # terminal; kept under _lock, folded into selftrace's counters at
         # each flush and finalize
         self._stage_sums = [0] * len(_STAGE_COUNTERS)
+        # the grammar's tail hits (pushes that grew the tail's exponent)
+        # at the last fold: each fold counts the hits since
+        self._tail_hits_folded = 0
         # -- streaming state (core/streaming.py) --------------------------------
         self._comm = comm                 # default comm for flush/finalize
         self.epoch = 0                    # committed flushes so far
@@ -342,12 +345,17 @@ class Recorder:
 
     def _fold_stage_sums_locked(self) -> None:
         """Add the record path's stage sums to selftrace's counters and
-        restart them (callers hold ``self._lock``)."""
+        restart them, with the grammar's tail hits since the last fold
+        (callers hold ``self._lock``)."""
         sums = self._stage_sums
+        hits = self.grammar.n_pushed - self.grammar.n_appended
         if sums[0]:
             for name, v in zip(_STAGE_COUNTERS, sums):
                 selftrace.count(name, v)
+            selftrace.count("record.grammar_tail_hits",
+                            hits - self._tail_hits_folded)
             self._stage_sums = [0] * len(_STAGE_COUNTERS)
+        self._tail_hits_folded = hits
 
     def _intern_call(self, fid: int, tidx: int, depth: int, norm: List[Any],
                      nret: Any, c1: int, key: Any = None, run: Any = None,
@@ -427,6 +435,7 @@ class Recorder:
             self._epoch_first_tick = None
             self.cst = CST()
             self.grammar = Sequitur()
+            self._tail_hits_folded = 0
             self.intra = IntraPatternTracker(
                 enabled=self.config.intra_patterns)
             self._records_at_flush_prev = self._records_at_flush

@@ -8,7 +8,12 @@ rules; the paper (following Pilgrim [19, 20]) shows rules of the form
 exponent-carrying Sequitur:
 
   * every symbol node is ``(sym, exp)``; appending a terminal equal to the
-    tail symbol increments the tail's exponent (streaming RLE),
+    tail symbol increments the tail's exponent (streaming RLE).  The start
+    rule's tail stays *open* while its run goes on: its digram with its
+    predecessor is indexed once, when the run closes (a push of another
+    symbol, or ``rules``/``expand``/``serialize``), so a repeat costs one
+    compare and one add, and per-terminal pushes give exactly the grammar
+    of one ``push(t, k)`` per maximal run,
   * digrams are keyed on both symbols *and* exponents, so a repeated loop
     body ``(a,n)(b,1)`` forms one rule regardless of ``n``,
   * adjacent equal symbols are always merged, which also removes the classic
@@ -93,6 +98,12 @@ class Sequitur:
         self.start = self._new_rule()
         self.index: Dict[Tuple[Key, Key], Symbol] = {}
         self.n_pushed = 0  # total terminals (with multiplicity)
+        # terminals (with multiplicity) pushed as a new node; the other
+        # n_pushed - n_appended grew the tail's exponent
+        self.n_appended = 0
+        # the start rule's tail while its run is open: a terminal node whose
+        # digram with its predecessor is not indexed yet
+        self._open: Optional[Symbol] = None
 
     # -- public API ---------------------------------------------------------
 
@@ -101,17 +112,25 @@ class Sequitur:
         if count <= 0:
             raise ValueError("count must be positive")
         self.n_pushed += count
-        g = self.start.guard
-        tail = g.prev
-        if not tail.is_guard and tail.rule is None and tail.term == terminal:
-            # streaming RLE: bump the tail's exponent in place
+        tail = self._open
+        if tail is not None:
+            if tail.term == terminal:
+                # streaming RLE: the open tail's run goes on
+                tail.exp += count
+                return
+            self._close_tail()
+        # the closing scan may have rewritten the tail: read it anew
+        tail = self.start.guard.prev
+        if tail.rule is None and tail.term == terminal:
+            # a repeat after a close reopens the tail (the guard has a rule)
             self._unindex_digram(tail.prev)
             tail.exp += count
-            self._scan_digram(tail.prev)
         else:
+            self.n_appended += count
             node = Symbol(terminal, None, count)
             self._splice_after(tail, node)
-            self._scan_digram(node.prev)
+            tail = node
+        self._open = tail
 
     def push_stream(self, terminals, backend: Optional[str] = None) -> None:
         """Append a whole terminal array with RLE pre-tokenization.
@@ -119,11 +138,9 @@ class Sequitur:
         Run boundaries are found in one batched pass
         (``encode_backend.run_boundaries``: NumPy or the grammar_stats
         kernel) and each maximal run enters the grammar as a single
-        ``push(term, run_len)`` -- the batch semantics of the existing
-        exponent API, so the expansion is always identical to per-terminal
-        pushes and the grammar is identical to calling
-        ``push(t, k)`` per run.  The ``python`` backend is the per-run
-        scalar reference."""
+        ``push(term, run_len)``, so the grammar is identical to calling
+        ``push(t, k)`` per run, and to per-terminal pushes.  The
+        ``python`` backend is the per-run scalar reference."""
         import numpy as np
         arr = np.asarray(terminals, dtype=np.int64).reshape(-1)
         n = int(arr.size)
@@ -147,6 +164,7 @@ class Sequitur:
             self.push(int(arr[s]), e - s)
 
     def rules(self) -> List[Rule]:
+        self._close_tail()
         seen: Dict[int, Rule] = {}
         stack = [self.start]
         while stack:
@@ -161,6 +179,7 @@ class Sequitur:
 
     def expand(self) -> List[int]:
         """Reconstruct the original terminal stream (lossless check)."""
+        self._close_tail()
         out: List[int] = []
 
         def walk(rule: Rule) -> None:
@@ -198,6 +217,14 @@ class Sequitur:
         return pack_uvarints(vals)
 
     # -- internals ----------------------------------------------------------
+
+    def _close_tail(self) -> None:
+        """Close the open tail's run: index its digram, with its final
+        exponent, per the digram-uniqueness invariant."""
+        tail = self._open
+        if tail is not None:
+            self._open = None
+            self._scan_digram(tail.prev)
 
     def _new_rule(self) -> Rule:
         r = Rule(self._next_rule_id)

@@ -198,6 +198,43 @@ def test_memo_hits_counter(tmp_path, offsets, on):
         assert c["record.calls"] == n and c["record.memo_hits"] == 0
 
 
+@pytest.mark.parametrize("flush,on", [(False, True), (True, True),
+                                      (False, False)])
+def test_grammar_tail_hits_counter(tmp_path, flush, on):
+    """A Listing-3-shaped loop pushes its run's kept terminal from its
+    third pwrite on, and each such push grows the grammar's tail; a flush
+    restarts the grammar (and the run), and each fold counts only the
+    hits since the last one; with selftrace off nothing is counted."""
+    n = 300
+    rec = Recorder(config=RecorderConfig(trace_dir=str(tmp_path / "t")))
+    selftrace.reset()
+    if on:
+        selftrace.enable()
+    fd = os.open(tmp_path / "data.bin", os.O_RDWR | os.O_CREAT, 0o644)
+    try:
+        attach(rec)
+        try:
+            for i in range(n):
+                if flush and i == n // 2:
+                    rec.flush()
+                posix.pwrite(fd, b"\x5a" * 64, i * 4096)
+            posix.fsync(fd)
+            posix.close(fd)
+        finally:
+            detach()
+        rec.finalize()
+        c = selftrace.snapshot()["counters"]
+    finally:
+        selftrace.disable()
+        selftrace.reset()
+    if not on:
+        assert c == {}
+        return
+    assert c["record.calls"] == n + 2
+    assert c["record.grammar_tail_hits"] == (n - 4 if flush else n - 2)
+    assert c["record.grammar_tail_hits"] == c["record.memo_hits"]
+
+
 def test_threadcomm_ranks_fold_their_own_sums(traced, tmp_path):
     """Ranks on threads, each with its recorder, fold into one set of
     counters: nothing is lost between them."""

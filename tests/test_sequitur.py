@@ -1,5 +1,7 @@
 """Sequitur (exponent-carrying) property + unit tests."""
 
+import random
+
 import pytest
 
 try:
@@ -93,3 +95,75 @@ def test_serialize_grammar_roundtrip():
     g = build([0, 1, 2, 0, 1, 2, 0, 1, 2])
     rules = parse_grammar(g.serialize())
     assert parse_grammar(serialize_grammar(rules)) == rules
+
+
+def _runs(seed, n_runs=60, alphabet=4, max_len=7):
+    """A random stream as maximal runs ``(terminal, length)``: adjacent
+    runs differ in their terminal."""
+    rng = random.Random(seed)
+    runs = []
+    for _ in range(n_runs):
+        t = rng.randrange(alphabet)
+        while runs and runs[-1][0] == t:
+            t = rng.randrange(alphabet)
+        runs.append((t, rng.randint(1, max_len)))
+    return runs
+
+
+def _assert_invariants(g):
+    """Digram uniqueness and rule utility over a closed grammar."""
+    rules = g.rules()
+    seen = {}
+    for r in rules:
+        items = list(r.body())
+        for left, right in zip(items, items[1:]):
+            key = (left.key(), right.key())
+            assert key not in seen, (repr(r), key)
+            seen[key] = left
+    for r in rules[1:]:
+        assert sum(s.exp for s in _all_refs(g, r)) >= 2, repr(r)
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_per_terminal_pushes_equal_one_push_per_run(seed):
+    """The open tail's digram is indexed when its run closes, so pushing
+    a run terminal by terminal gives the grammar of one ``push(t, k)``."""
+    for k in range(10):
+        runs = _runs(seed * 100 + k)
+        stream = [t for t, n in runs for _ in range(n)]
+        per_run = Sequitur()
+        for t, n in runs:
+            per_run.push(t, n)
+        g = build(stream)
+        assert g.serialize() == per_run.serialize()
+        assert g.expand() == stream
+        assert g.n_pushed == len(stream)
+        assert g.n_appended == len(runs)      # one node a run
+        _assert_invariants(g)
+
+
+@pytest.mark.parametrize("seed", range(6))
+def test_serialize_mid_run_then_repeat(seed):
+    """A read closes the open tail; a repeat after it reopens the tail,
+    and the grammar stays lossless with both invariants."""
+    rng = random.Random(seed)
+    runs = _runs(seed, n_runs=40, alphabet=3, max_len=5)
+    stream = [t for t, n in runs for _ in range(n)]
+    g = Sequitur()
+    for i, t in enumerate(stream):
+        g.push(t)
+        if rng.random() < 0.3:
+            assert list(expand_grammar(parse_grammar(g.serialize()))) \
+                == stream[:i + 1]
+    assert list(expand_grammar(parse_grammar(g.serialize()))) == stream
+    assert g.expand() == stream
+    _assert_invariants(g)
+
+
+def test_run_prefix_makes_no_rule():
+    """``a b b a b b b``: the prefix ``a b^2`` of the last run matches the
+    first digram, but the run is still open, so no rule forms; the
+    closed digram ``a b^3`` is new."""
+    g = build([0, 1, 1, 0, 1, 1, 1])
+    assert [[(2 * t, n) for t, n in [(0, 1), (1, 2), (0, 1), (1, 3)]]] \
+        == parse_grammar(g.serialize())
