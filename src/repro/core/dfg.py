@@ -172,9 +172,11 @@ def pattern_class(sig) -> str:
     through an arithmetic offset run), or ``const`` (a fixed or purely
     rank-linear offset).  Rank-symbolic components do NOT change the
     class: SPMD ranks whose offsets differ only by the rank project to
-    the same label."""
-    if sig.enc is None:
+    the same label.  A path pattern's fields do not count: a call whose
+    only run values are its path's fields is ``plain``."""
+    if sig.enc is None or not (sig.enc[4] or sig.enc[5]):
         return "plain"
+    # a call's run values are all concrete or all IterPatterns
     return "run" if sig.enc[3] else "const"
 
 

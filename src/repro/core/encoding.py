@@ -10,6 +10,10 @@ kinds introduced by the compression algorithm (paper Section 3.2):
   * ``IterPattern(a, b)``  -- intra-process offsets following ``i*a + b``
   * ``RankPattern(a, b)``  -- inter-process components following ``rank*a + b``
 
+and the path-pattern value ``PathPattern(template, fields)``: a path whose
+decimal fields are split out of its literal text
+(``patterns.split_path``), so that the fields can follow ``i*a + b`` too.
+
 Pattern components may nest (Fig. 3(c): ``lseek((20, (10, 0)))`` encodes an
 iteration stride of 20 whose base is rank-linear ``10*rank + 0``).
 """
@@ -159,6 +163,17 @@ class RankPattern:
 
 
 @dataclass(frozen=True)
+class PathPattern:
+    """A path as its literal pieces and the decimal fields between them:
+    ``template`` holds ``len(fields) + 1`` strings, and the path is
+    ``template[0] + str(fields[0]) + template[1] + ...``.  Each field is an
+    int or an ``IterPattern`` (the i-th call of a run)."""
+
+    template: Tuple[str, ...]
+    fields: Tuple[Any, ...]
+
+
+@dataclass(frozen=True)
 class Handle:
     """Unified file-handle id (paper Section 3.2.2: opaque MPI_File handles
     are replaced by a group-wide unique id at open time)."""
@@ -179,6 +194,18 @@ _T_ITERPAT = 8
 _T_RANKPAT = 9
 _T_TUPLE = 10
 _T_DICT = 11
+_T_PATHPAT = 12
+
+
+def _write_str(out: bytearray, s: str) -> None:
+    raw = s.encode("utf-8")
+    write_uvarint(out, len(raw))
+    out.extend(raw)
+
+
+def _read_str(buf: bytes, pos: int) -> Tuple[str, int]:
+    n, pos = read_uvarint(buf, pos)
+    return buf[pos : pos + n].decode("utf-8"), pos + n
 
 
 def encode_value(out: bytearray, v: Any) -> None:
@@ -195,10 +222,8 @@ def encode_value(out: bytearray, v: Any) -> None:
         out.append(_T_FLOAT)
         out.extend(struct.pack("<d", v))
     elif isinstance(v, str):
-        raw = v.encode("utf-8")
         out.append(_T_STR)
-        write_uvarint(out, len(raw))
-        out.extend(raw)
+        _write_str(out, v)
     elif isinstance(v, (bytes, bytearray)):
         out.append(_T_BYTES)
         write_uvarint(out, len(v))
@@ -214,6 +239,14 @@ def encode_value(out: bytearray, v: Any) -> None:
         out.append(_T_RANKPAT)
         write_svarint(out, v.a)
         write_svarint(out, v.b)
+    elif isinstance(v, PathPattern):
+        # field count, the count + 1 literal pieces, then each field
+        out.append(_T_PATHPAT)
+        write_uvarint(out, len(v.fields))
+        for piece in v.template:
+            _write_str(out, piece)
+        for f in v.fields:
+            encode_value(out, f)
     elif isinstance(v, (tuple, list)):
         out.append(_T_TUPLE)
         write_uvarint(out, len(v))
@@ -246,8 +279,7 @@ def decode_value(buf: bytes, pos: int) -> Tuple[Any, int]:
     if tag == _T_FLOAT:
         return struct.unpack_from("<d", buf, pos)[0], pos + 8
     if tag == _T_STR:
-        n, pos = read_uvarint(buf, pos)
-        return buf[pos : pos + n].decode("utf-8"), pos + n
+        return _read_str(buf, pos)
     if tag == _T_BYTES:
         n, pos = read_uvarint(buf, pos)
         return bytes(buf[pos : pos + n]), pos + n
@@ -262,6 +294,17 @@ def decode_value(buf: bytes, pos: int) -> Tuple[Any, int]:
         a, pos = read_svarint(buf, pos)
         b, pos = read_svarint(buf, pos)
         return RankPattern(a, b), pos
+    if tag == _T_PATHPAT:
+        n, pos = read_uvarint(buf, pos)
+        pieces = []
+        for _ in range(n + 1):
+            piece, pos = _read_str(buf, pos)
+            pieces.append(piece)
+        fields = []
+        for _ in range(n):
+            f, pos = decode_value(buf, pos)
+            fields.append(f)
+        return PathPattern(tuple(pieces), tuple(fields)), pos
     if tag == _T_TUPLE:
         n, pos = read_uvarint(buf, pos)
         items = []

@@ -17,6 +17,17 @@ Calls with multiple OFFSET-role arguments are tracked jointly (a shared run
 index with per-component strides), so e.g. ``(offset, whence)`` pairs or
 framework step counters compress with the same machinery.
 
+Paths are patterns too (paper Section 3.2.1 applied to PATH-role
+arguments): :func:`split_path` splits a path into its literal template and
+its decimal fields.  The template joins the pattern key and the fields join
+the call's run values, after its OFFSET-role values, so ``open(f"file.{i}")``
+for ``i = 0, 1, 2, ...`` is one run and its calls share one signature, whose
+path is a ``PathPattern`` of ``IterPattern`` fields.  A path with no field
+stays a literal string.  Where a call's only run values are its paths'
+fields, a call that repeats its run's first values (the same path again)
+is stored concretely and starts no stride (``repeat_concrete``): reopening
+one file costs one signature, as a literal path does.
+
 ``IntraPatternTracker.encode_many`` is the batched entry point: it encodes a
 whole sequence of calls for one key at once, finding arithmetic runs with
 the segmentation helper :func:`arith_segments`, and is result- and
@@ -34,12 +45,54 @@ run is a new ``_RunState``, so the terminal cannot outlive its run.
 
 from __future__ import annotations
 
+import re
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
 from .encoding import IterPattern
+
+# a path's digit runs (ASCII only: a run renders back from its int)
+_DIGIT_RUNS = re.compile(r"([0-9]+)")
+# the longest digit run that is a field: every such int fits in int64
+MAX_FIELD_DIGITS = 18
+
+
+def split_path(path: str) -> Tuple[Any, Tuple[int, ...]]:
+    """``(template, fields)`` of a path.  A field is a maximal run of
+    decimal digits with no leading zero (or exactly ``0``) of at most
+    :data:`MAX_FIELD_DIGITS` digits; any other run stays literal, so
+    ``render_path(*split_path(p)) == p`` for every string.  The template
+    is the tuple of ``len(fields) + 1`` literal pieces around the fields,
+    or, for a path with no field, the path itself."""
+    parts = _DIGIT_RUNS.split(path)
+    if len(parts) == 1:
+        return path, ()
+    pieces = [parts[0]]
+    fields = []
+    for k in range(1, len(parts), 2):
+        run = parts[k]
+        if len(run) <= MAX_FIELD_DIGITS and (run[0] != "0" or len(run) == 1):
+            fields.append(int(run))
+            pieces.append(parts[k + 1])
+        else:
+            pieces[-1] += run + parts[k + 1]
+    if not fields:
+        return path, ()
+    return tuple(pieces), tuple(fields)
+
+
+def render_path(template: Any, fields: Sequence[int]) -> str:
+    """The literal path of ``template`` with int ``fields`` (the inverse
+    of :func:`split_path`)."""
+    if not fields:
+        return template
+    out = [template[0]]
+    for f, piece in zip(fields, template[1:]):
+        out.append(str(f))
+        out.append(piece)
+    return "".join(out)
 
 
 @dataclass
@@ -56,6 +109,18 @@ class _RunState:
 Encoded = Union[int, IterPattern]
 
 
+def continues(run: _RunState, vals: Sequence[int]) -> bool:
+    """Whether ``vals`` are ``run``'s next values (``base + index *
+    stride``); ``run.stride`` must be set."""
+    if len(vals) != len(run.base):
+        return False
+    i = run.index
+    for v, b, s in zip(vals, run.base, run.stride):
+        if v != b + i * s:
+            return False
+    return True
+
+
 class IntraPatternTracker:
     """Per-process tracker; keys must be hashable and derivable by the reader
     from decoded records (it uses the same key function on decoded args)."""
@@ -65,19 +130,24 @@ class IntraPatternTracker:
         # pattern key -> its active run
         self.runs: Dict[Any, _RunState] = {}
 
-    def encode(self, key: Any, offsets: Sequence[int]) -> List[Encoded]:
-        """Encode the OFFSET-role values of one call."""
+    def encode(self, key: Any, offsets: Sequence[int],
+               repeat_concrete: bool = False) -> List[Encoded]:
+        """Encode the run values of one call: its OFFSET-role values, then
+        its paths' fields.  With ``repeat_concrete`` (the values are all
+        path fields) a call whose values equal its run's first starts no
+        stride and is encoded concretely."""
         vals = tuple(int(v) for v in offsets)
         if not self.enabled or not vals:
             return list(vals)
-        return self._encode(key, self.runs.get(key), vals)
+        return self._encode(key, self.runs.get(key), vals, repeat_concrete)
 
     def encode_run(self, key: Any, run: Optional[_RunState],
-                   offsets: List[int], keep: bool
+                   offsets: Sequence[int], keep: bool,
+                   repeat_concrete: bool = False
                    ) -> Tuple[Optional[_RunState], List[Encoded]]:
         """:meth:`encode` for the record path, given ``key``'s run as it
-        looked it up (``runs.get(key)``): one call's OFFSET-role values
-        (ints), whose signature the caller keeps in the run if ``keep``.
+        looked it up (``runs.get(key)``): one call's run values (ints),
+        whose signature the caller keeps in the run if ``keep``.
 
         Returns what :meth:`encode` returns, after the run it belongs to:
         the run whose ``terminal`` and ``depth`` the caller sets once it
@@ -86,18 +156,22 @@ class IntraPatternTracker:
         vals = tuple(int(v) for v in offsets)
         if not self.enabled or not vals:
             return None, list(vals)
-        encoded = self._encode(key, run, vals)
+        encoded = self._encode(key, run, vals, repeat_concrete)
         if not keep or not isinstance(encoded[0], IterPattern):
             return None, encoded
         return run, encoded
 
     def _encode(self, key: Any, run: Optional[_RunState],
-                vals: Tuple[int, ...]) -> List[Encoded]:
+                vals: Tuple[int, ...],
+                repeat_concrete: bool = False) -> List[Encoded]:
         """:meth:`encode` given ``key``'s run as looked up."""
         if run is None:
             self.runs[key] = _RunState(index=1, base=vals, stride=None)
             return list(vals)
         if run.stride is None:
+            if repeat_concrete and vals == run.base:
+                # the same path again: the run stays at its first call
+                return list(vals)
             stride = tuple(v - b for v, b in zip(vals, run.base))
             run.stride = stride
             run.index = 2

@@ -9,6 +9,8 @@ post-processing support):
   RankPattern values      -> resolved with the reader's rank
   IterPattern values      -> resolved with a per-pattern-key run counter
                              (exact mirror of the runtime tracker)
+  PathPattern values      -> their fields resolved by the same counter,
+                             then rendered as the literal path
 
 The record-expansion methods here are thin compatibility shims over
 :class:`repro.core.traceview.TraceView` (``self.view()``), which holds the

@@ -9,7 +9,8 @@ order:
   * argument normalization by role (paths, unified handle ids, buffer
     lengths -- paper §2.2.1/§3.2.2),
   * runtime filtering by path prefix and layer (paper §2.1.1),
-  * intra-process I/O pattern encoding of OFFSET-role args (paper §3.2.1),
+  * intra-process I/O pattern encoding of OFFSET-role args and of the
+    decimal fields of PATH-role args (paper §3.2.1),
   * CST interning of the call signature (paper §3.1),
   * Sequitur grammar append (paper §3.1),
   * timestamp buffering (paper §2.2.1).
@@ -37,11 +38,11 @@ import numpy as np
 
 from .comm import Comm, SoloComm
 from .cst import CST
-from .encoding import Handle
+from .encoding import Handle, PathPattern
 from .interprocess import (deserialize_rank_state, make_rank_state,
                            materialize_state, merge_serialized_states,
                            serialize_rank_state)
-from .patterns import IntraPatternTracker
+from .patterns import IntraPatternTracker, continues, split_path
 from .sequitur import Sequitur, concat_grammars
 from .specs import DATA_FUNCS, REGISTRY, FnSpec, FunctionRegistry, Role
 from .timestamps import TimestampBuffer, compress_timestamps
@@ -78,7 +79,8 @@ def _env_float(name: str, minimum: float = 0.0) -> Optional[float]:
 # selftrace counters of the record path, in Recorder._stage_sums's order
 _STAGE_COUNTERS = ("record.calls", "record.total_ns", "record.patterns_ns",
                    "record.signature_ns", "record.grammar_ns",
-                   "record.wrapped_us", "record.memo_hits")
+                   "record.wrapped_us", "record.memo_hits",
+                   "record.path_calls", "record.path_ns")
 
 # Types whose equal values have equal signature encodings.  A run keeps
 # its signature's terminal only for calls whose SIZE/VAL arguments and
@@ -248,8 +250,9 @@ class Recorder:
         # with selftrace on: record() calls, then ns in record() whole, in
         # its patterns, signature and grammar stages, then the traced
         # calls' own ticks (us), then the calls that reused their run's
-        # terminal; kept under _lock, folded into selftrace's counters at
-        # each flush and finalize
+        # terminal, then the recorded calls with a PATH-role argument and
+        # ns in their paths' split; kept under _lock, folded into
+        # selftrace's counters at each flush and finalize
         self._stage_sums = [0] * len(_STAGE_COUNTERS)
         # the grammar's tail hits (pushes that grew the tail's exponent)
         # at the last fold: each fold counts the hits since
@@ -360,22 +363,34 @@ class Recorder:
     def _intern_call(self, fid: int, tidx: int, depth: int, norm: List[Any],
                      nret: Any, c1: int, key: Any = None, run: Any = None,
                      keep: bool = False, slots: Tuple[int, ...] = (),
-                     vals: Sequence[int] = (), ret_off: bool = False
+                     vals: Sequence[int] = (), ret_off: bool = False,
+                     paths: Tuple[Tuple[int, Any, int], ...] = ()
                      ) -> Tuple[int, Any, int]:
         """The record code's path for a call that does not continue a run
         whose terminal it can reuse: its normalised arguments ``norm``
         (None at the OFFSET-role ``slots``) and return ``nret``, and, where
-        the call has OFFSET-role values ``vals`` (the return last if
-        ``ret_off``), their pattern ``key`` and its ``run`` as looked up.
+        the call has run values ``vals`` (its OFFSET-role values, then the
+        fields of its paths, the return last if ``ret_off``), their pattern
+        ``key`` and its ``run`` as looked up.  ``paths`` holds a ``(slot,
+        template, field count)`` for each PATH-role argument, in order.
         Encodes the values, interns the signature, and keeps its terminal
         in the run it continues if ``keep``.  Returns the terminal, the
         return as encoded, and the ns clock at the end of the signature
         stage (0 unless the caller's stage clock ``c1`` is)."""
         kept = None
         if key is not None:
-            kept, encoded = self.intra.encode_run(key, run, vals, keep)
+            # a call whose run values are all path fields stores a
+            # repeated path concretely
+            kept, encoded = self.intra.encode_run(
+                key, run, vals, keep, not slots and not ret_off)
             for slot, val in zip(slots, encoded):
                 norm[slot] = val
+            k = len(slots)
+            for slot, template, n in paths:
+                if n:
+                    norm[slot] = PathPattern(template,
+                                             tuple(encoded[k:k + n]))
+                    k += n
             if ret_off:
                 nret = encoded[-1]
         c2 = time.perf_counter_ns() if c1 else 0
@@ -947,11 +962,13 @@ def record_code(registry: FunctionRegistry, fid: int,
     run time.  An argument past the spec's counts as VAL.
 
     The code normalises each argument by its role (paths filtered by
-    ``path_prefixes``, handles given unified ids, buffers measured) and
-    the return value.  A call with OFFSET-role values looks its pattern
-    key's run up: if the run kept a terminal at this call depth and the
-    values are the run's next (``base + index * stride``), the call only
-    advances the run, pushes the terminal and appends its timestamp row.
+    ``path_prefixes`` and split into template and decimal fields,
+    handles given unified ids, buffers measured) and the return value.
+    A call with run values (OFFSET-role values, then its paths' fields)
+    looks its pattern key's run up: if the run kept a terminal at this
+    call depth and the values are the run's next (``base + index *
+    stride``), the call only advances the run, pushes the terminal and
+    appends its timestamp row.
     Every other call goes through :meth:`Recorder._intern_call`.  A call
     whose SIZE/VAL arguments or return (unless OFFSET-role) are not of a
     type in ``_KEPT_TYPES`` neither keeps nor reuses a terminal."""
@@ -965,7 +982,8 @@ def record_code(registry: FunctionRegistry, fid: int,
     if code is None:
         ns: Dict[str, Any] = {"_st": selftrace, "_ns": time.perf_counter_ns,
                               "_KEPT": _KEPT_TYPES, "Handle": Handle,
-                              "record_code": record_code}
+                              "record_code": record_code,
+                              "_split": split_path, "continues": continues}
         exec(compile(_record_source(spec, nargs),  # noqa: S102 (§2.1)
                      f"<recorder-record:{spec.name}/{nargs}>", "exec"), ns)
         code = codes[key] = ns["record_call"]
@@ -994,8 +1012,10 @@ def _record_source(spec: FnSpec, nargs: int) -> str:
     if Role.PATH in roles:
         out.append("prefixes = rec.config.path_prefixes")
     skip = ["    rec.n_skipped += 1", "    return"]
-    # per argument: its value in the signature, and what joins the key
+    # per argument: its value in the signature, and what joins the key;
+    # per PATH-role argument: its slot and index
     norm, hids, parts, offs, slots, kept, data = [], [], [], [], [], [], []
+    paths = []
     for i, role in enumerate(roles):
         a, v = f"a{i}", f"v{i}"
         if role == Role.PATH:
@@ -1008,8 +1028,11 @@ def _record_source(spec: FnSpec, nargs: int) -> str:
                 out += ["    if ret is not None:",
                         "        rec._untracked.add(ret)"]
             out += skip
+            # the literal path, until its fields are encoded; the
+            # template joins the key
+            paths.append((len(norm), i))
             norm.append(v)
-            parts.append(v)
+            parts.append(f"p{i}")
         elif role == Role.HANDLE:
             # a handle from before tracing started is registered late
             out += [f"if {a} in rec._untracked:"] + skip + [
@@ -1035,6 +1058,18 @@ def _record_source(spec: FnSpec, nargs: int) -> str:
             parts.append(a)
             if role == Role.SIZE:
                 data.append(a)
+    if paths:
+        # each path split into its template (the literal path where it has
+        # no field) and its decimal fields, which join the run values
+        out += ["cp = _ns() if c1 else 0",
+                "if rec.intra.enabled:"]
+        out += [f"    p{i}, f{i} = _split(v{i})" for _, i in paths]
+        out += ["else:"]
+        out += [f"    p{i}, f{i} = v{i}, ()" for _, i in paths]
+        out += ["if c1:",
+                "    sums = rec._stage_sums",
+                "    sums[7] += 1",
+                "    sums[8] += _ns() - cp"]
     if spec.ret_role == Role.HANDLE:
         # layered opens (shard_open -> posix.open) return the same raw
         # handle: they share one unified id (paper Section 3.2.2)
@@ -1053,6 +1088,11 @@ def _record_source(spec: FnSpec, nargs: int) -> str:
     miss = (f"terminal, nret, c3 = rec._intern_call(fid, tidx, depth, "
             f"[{', '.join(norm)}], nret, c1")
 
+    # the run values of a call with PATH-role arguments are known at run
+    # time only: its OFFSET-role values, its paths' fields, its return last
+    fields = [f"f{i}" for _, i in paths]
+    path_arg = _tuple([f"({slot}, p{i}, len(f{i}))" for slot, i in paths])
+
     def run_block(indent: str, key_ret: str, vals: List[str], keep: List[str],
                   ret_off: bool) -> List[str]:
         """The pattern key's run looked up: a call whose ``vals`` are the
@@ -1060,14 +1100,26 @@ def _record_source(spec: FnSpec, nargs: int) -> str:
         it; any other call misses.  The run's length is compared too: a
         call of another arity, or an OFFSET-role return that is not an
         int, shares its key with values of another count."""
-        nxt = " and ".join(f"{x} == run.base[{k}] + run.index * "
-                           f"run.stride[{k}]" for k, x in enumerate(vals))
-        lines = [f"keep = {' and '.join(keep) or 'True'}",
+        if paths:
+            static = vals[:-1] if ret_off else vals
+            pre = ["vals = " + " + ".join(
+                ([_tuple(static)] if static else []) + fields
+                + (["(nret,)"] if ret_off else []))]
+            nxt = "continues(run, vals)"
+            given, extra = "vals", f", {path_arg}"
+        else:
+            pre = []
+            nxt = f"len(run.base) == {len(vals)} and " + " and ".join(
+                f"{x} == run.base[{k}] + run.index * run.stride[{k}]"
+                for k, x in enumerate(vals))
+            given, extra = f"[{', '.join(vals)}]", ""
+        lines = pre + [
+                 f"keep = {' and '.join(keep) or 'True'}",
                  f"key = ({', '.join(head + [key_ret])})",
                  "run = rec.intra.runs.get(key)",
                  "if (keep and run is not None and run.depth == depth",
                  "        and run.terminal is not None",
-                 f"        and len(run.base) == {len(vals)} and {nxt}):",
+                 f"        and {nxt}):",
                  "    run.index += 1",
                  "    terminal = run.terminal",
                  "    if c1:",
@@ -1079,13 +1131,20 @@ def _record_source(spec: FnSpec, nargs: int) -> str:
             lines.append("    nret = None")
         lines += ["else:",
                   f"    {miss}, key, run, keep, {_tuple(slots)}, "
-                  f"[{', '.join(vals)}], {ret_off})"]
+                  f"{given}, {ret_off}{extra})"]
         return [indent + x for x in lines]
 
     key_ret = "('h', nret.id) if isinstance(nret, Handle) else nret"
     ret_kept = kept + ["type(nret) in _KEPT"]
-    plain = ([f"{miss})"] if not offs
-             else run_block("", key_ret, offs, ret_kept, False))
+    if offs:
+        plain = run_block("", key_ret, offs, ret_kept, False)
+    elif paths:
+        # paths with no field and no OFFSET-role value: no run
+        plain = ([f"if {' or '.join(fields)}:"]
+                 + run_block("    ", key_ret, offs, ret_kept, False)
+                 + ["else:", f"    {miss})"])
+    else:
+        plain = [f"{miss})"]
     if spec.ret_role == Role.OFFSET:
         # an int OFFSET-role return (lseek's resulting offset) joins the
         # run; it cannot be part of the pattern key then

@@ -19,7 +19,8 @@ columnar materialization
     decompressed lazily and memoized, only when a query touches them.
 
 rank-symbolic resolution
-    ``RankPattern`` / ``IterPattern`` offsets stay symbolic in the columns.
+    ``RankPattern`` / ``IterPattern`` offsets stay symbolic in the columns,
+    and so do the ``IterPattern`` fields of a ``PathPattern`` path.
     Queries that need concrete per-record extents (consistency analysis)
     walk the terminal stream ONCE per unique CFG -- every rank sharing a
     CFG has the same stream -- keeping each offset as a linear function of
@@ -46,9 +47,9 @@ from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 import numpy as np
 
 from . import dfg as _dfg
-from .encoding import (Handle, IterPattern, RankPattern,
+from .encoding import (Handle, IterPattern, PathPattern, RankPattern,
                        concat_signature_columns, decode_signatures_batch)
-from .patterns import IntraPatternDecoder
+from .patterns import IntraPatternDecoder, render_path
 from .reader import Record, _resolve_rank
 from .sequitur import (_topo_order, expand_grammar, expand_grammar_reversed,
                        parse_grammar, terminal_counts, terminal_positions)
@@ -63,6 +64,9 @@ _IO_LAYERS = ("posix", "shardio")
 _WRITE_FUNCS = ("pwrite", "shard_write_at")
 _I64_SAFE = 1 << 62
 _NO_HANDLE = object()
+# the path of an open whose fields follow a run: it depends on the run
+# index, so the open's signature alone does not give it
+_SYMBOLIC = object()
 
 
 class _SpanBail(Exception):
@@ -94,8 +98,9 @@ def _lin0(v: Any) -> Tuple[int, int]:
 def _derive_key(func_id: int, tidx: int, args: tuple, ret: Any,
                 roles: Sequence[str], ret_is_offset: bool) -> tuple:
     """The pattern-run decode key of one call: non-offset args split into
-    handle ids and key parts (single definition site -- the decoder state
-    only matches the runtime tracker if every path builds keys this way)."""
+    handle ids and key parts, a path pattern giving its template (single
+    definition site -- the decoder state only matches the runtime tracker
+    if every path builds keys this way)."""
     handle_ids: List[int] = []
     keyparts: List[Any] = []
     for j, a in enumerate(args):
@@ -104,6 +109,8 @@ def _derive_key(func_id: int, tidx: int, args: tuple, ret: Any,
             continue
         if isinstance(a, Handle):
             handle_ids.append(a.id)
+        elif isinstance(a, PathPattern):
+            keyparts.append(a.template)
         else:
             keyparts.append(a)
     key_ret = None if ret_is_offset else (
@@ -149,10 +156,13 @@ class _SigInfo:
     """Per-CST-entry derived metadata (role-indexed columns)."""
 
     __slots__ = ("name", "layer", "is_data", "is_io_layer", "size",
-                 "size_symbolic", "handle", "enc")
+                 "size_symbolic", "handle", "enc", "open_path")
 
     def __init__(self) -> None:
         self.enc: Optional[tuple] = None
+        # an open that returns a handle: the path it binds (``_SYMBOLIC``
+        # where its fields follow a run); None for every other call
+        self.open_path: Any = None
 
 
 def make_sig_info(cols, functions: Dict[int, Dict[str, Any]],
@@ -184,12 +194,27 @@ def make_sig_info(cols, functions: Dict[int, Dict[str, Any]],
         and not isinstance(ret, int)
     s.handle = next((v.id for v, role in zip(args, roles)
                      if role == "handle" and hasattr(v, "id")), _NO_HANDLE)
+    if s.name in _OPEN_FUNCS and hasattr(ret, "id"):
+        path = args[0]
+        if not isinstance(path, PathPattern):
+            s.open_path = str(path)
+        elif all(type(f) is int for f in path.fields):
+            s.open_path = render_path(path.template, path.fields)
+        else:
+            s.open_path = _SYMBOLIC
     off_slots = [j for j, r in enumerate(roles)
                  if r == "offset" and j < len(args)]
-    if off_slots or ret_is_offset:
+    # (slot, template, field count) of each path pattern: its fields
+    # follow the OFFSET-role values among the run values
+    path_slots = tuple((j, v.template, len(v.fields))
+                       for j, v in enumerate(args)
+                       if isinstance(v, PathPattern))
+    if off_slots or ret_is_offset or path_slots:
         key = _derive_key(int(cols.func_id[t]), int(cols.thread[t]),
                           args, ret, roles, ret_is_offset)
         enc = [args[j] for j in off_slots]
+        for j, _template, _n in path_slots:
+            enc.extend(args[j].fields)
         if ret_is_offset:
             enc.append(ret)
         patsig = tuple((v.a, v.b) if isinstance(v, IterPattern) else v
@@ -200,7 +225,7 @@ def make_sig_info(cols, functions: Dict[int, Dict[str, Any]],
         key_rankdep = (_contains_rankpattern(key[3])
                        or _contains_rankpattern(key[4]))
         s.enc = (key, tuple(enc), patsig, has_iter, off_slots,
-                 ret_is_offset, key_rankdep)
+                 ret_is_offset, key_rankdep, path_slots)
     return s
 
 
@@ -221,8 +246,10 @@ def per_file_fold(rules: List[List[Tuple[int, int]]], sigs, cols,
     Same rule/read-set memo walk as the PR 8 sublinear path (a rule's
     contribution depends only on the live bindings of the handles its
     subtree reads; idempotent state updates collapse exponents in closed
-    form).  Raises RecursionError on pathologically deep grammars --
-    callers fall back to :func:`per_file_fold_linear`.
+    form).  A stream with an open whose path follows a run binds a path
+    that changes with the run index, so it takes the linear walk, which
+    decodes each such path.  Raises RecursionError on pathologically deep
+    grammars -- callers fall back to :func:`per_file_fold_linear`.
     """
     n = len(rules)
     # static per-rule summaries, children before parents: the handles a
@@ -242,9 +269,11 @@ def per_file_fold(rules: List[List[Tuple[int, int]]], sigs, cols,
                 s = sigs[x + toff]
                 if s.is_data and s.handle is not _NO_HANDLE:
                     rd.add(s.handle)
-                if s.name in _OPEN_FUNCS and hasattr(cols.ret[x + toff],
-                                                     "id"):
-                    up[cols.ret[x + toff].id] = str(cols.args[x + toff][0])
+                if s.open_path is _SYMBOLIC:
+                    return per_file_fold_linear(rules, sigs, cols, live0,
+                                                toff)
+                if s.open_path is not None:
+                    up[cols.ret[x + toff].id] = s.open_path
         reads[i] = rd
         upd[i] = up
 
@@ -275,9 +304,8 @@ def per_file_fold(rules: List[List[Tuple[int, int]]], sigs, cols,
                     add(contrib, walk(x), exp - 1)
             else:
                 s = sigs[x + toff]
-                if s.name in _OPEN_FUNCS and hasattr(cols.ret[x + toff],
-                                                     "id"):
-                    live[cols.ret[x + toff].id] = str(cols.args[x + toff][0])
+                if s.open_path is not None:
+                    live[cols.ret[x + toff].id] = s.open_path
                 if s.is_data:
                     k = "?" if s.handle is _NO_HANDLE \
                         else live.get(s.handle)
@@ -295,18 +323,55 @@ def per_file_fold_linear(rules: List[List[Tuple[int, int]]], sigs, cols,
                          ) -> Tuple[Dict[Any, Tuple[int, int]],
                                     Dict[int, str]]:
     """Linear-stream reference (and deep-grammar fallback) for
-    :func:`per_file_fold`: one walk of the expanded stream."""
+    :func:`per_file_fold`: one walk of the expanded stream.  Paths that
+    follow a run are decoded as the reader does (a stream's runs start
+    afresh with it: a segment's first call of a run is concrete)."""
     handles: Dict[int, str] = dict(live0)
     per: Dict[Any, Tuple[int, int]] = {}
+    decoder = IntraPatternDecoder()
     for t in expand_grammar(rules):
         s = sigs[t + toff]
-        if s.name in _OPEN_FUNCS and hasattr(cols.ret[t + toff], "id"):
-            handles[cols.ret[t + toff].id] = str(cols.args[t + toff][0])
+        if s.open_path is not None:
+            path = s.open_path
+            if s.enc is not None:
+                # every open of the run's key is fed, the concrete first
+                path = _decoded_args(s, cols.args[t + toff],
+                                     cols.ret[t + toff], decoder)[0][0]
+            handles[cols.ret[t + toff].id] = path
         if s.is_data:
             key = "?" if s.handle is _NO_HANDLE else handles.get(s.handle)
             b, c = per.get(key, (0, 0))
             per[key] = (b + s.size, c + 1)
     return per, handles
+
+
+def _decoded_args(s: _SigInfo, args: tuple, ret: Any,
+                  decoder: IntraPatternDecoder,
+                  roles: Sequence[str] = ()) -> Tuple[tuple, Any]:
+    """One call's arguments and return with its run values resolved by
+    ``decoder``: OFFSET-role values (and an OFFSET-role return) as ints,
+    path patterns as literal paths.  Reuses the per-terminal derivation
+    of ``s.enc``; only a rank-dependent key (RankPattern in its parts) is
+    re-derived, from ``args`` as rank-resolved and the spec's ``roles``."""
+    key, _, _, _, off_slots, ret_is_offset, key_rankdep, path_slots = s.enc
+    if key_rankdep:
+        key = _derive_key(key[0], key[1], args, ret, roles, ret_is_offset)
+    enc = [args[j] for j in off_slots]
+    for j, _template, _n in path_slots:
+        enc.extend(args[j].fields)
+    if ret_is_offset:
+        enc.append(ret)
+    dec = decoder.decode(key, enc)
+    args = list(args)
+    for j, v in zip(off_slots, dec):
+        args[j] = v
+    k = len(off_slots)
+    for j, template, n in path_slots:
+        args[j] = render_path(template, dec[k:k + n])
+        k += n
+    if ret_is_offset:
+        ret = dec[-1]
+    return tuple(args), ret
 
 
 def _contrib_dicts(contrib: Dict[Any, Tuple[int, int]]
@@ -697,7 +762,8 @@ class TraceView:
 
         Fast path: grammar-weighted, using first/last terminal positions to
         prove each data call sees exactly one open path for its handle.
-        Ambiguous handle/path reuse falls back to one exact stream walk.
+        Ambiguous handle/path reuse, and opens whose paths follow a run,
+        fall back to one exact stream walk.
         """
         cached = self._perfile.get(u)
         if cached is not None:
@@ -705,15 +771,17 @@ class TraceView:
         counts = self.cfg_terminal_counts(u)
         sigs = self._sigs
         cols = self.columns
+        if any(sigs[t].open_path is _SYMBOLIC for t in counts):
+            per = self._perfile[u] = self._per_file_walk(u)
+            return per
         opens: Dict[int, set] = {}
         open_first: Dict[int, int] = {}
         data_terms = []
         need_pos = False
         for t in counts:
             s = sigs[t]
-            if s.name in _OPEN_FUNCS and hasattr(cols.ret[t], "id"):
-                opens.setdefault(cols.ret[t].id, set()).add(
-                    str(cols.args[t][0]))
+            if s.open_path is not None:
+                opens.setdefault(cols.ret[t].id, set()).add(s.open_path)
                 need_pos = True
             if s.is_data:
                 data_terms.append(t)
@@ -723,7 +791,7 @@ class TraceView:
             first, last = self._cfg_positions(u)
             for t in counts:
                 s = sigs[t]
-                if s.name in _OPEN_FUNCS and hasattr(cols.ret[t], "id"):
+                if s.open_path is not None:
                     h = cols.ret[t].id
                     p = first[t]
                     if h not in open_first or p < open_first[h]:
@@ -968,7 +1036,7 @@ class TraceView:
             vals0 = None  # (coef, const, ca, va, kid) of offset slot 0
             if s.enc is not None:
                 (key, enc, patsig, has_iter, off_slots, _ret_is_offset,
-                 key_rankdep) = s.enc
+                 key_rankdep, _path_slots) = s.enc
                 if key_rankdep:
                     raise _SpanBail
                 if not has_iter:
@@ -1105,7 +1173,7 @@ class TraceView:
             vals: Optional[List[Tuple[int, int]]] = None
             if s.enc is not None:
                 (key, enc, patsig, has_iter, off_slots, ret_is_offset,
-                 key_rankdep) = s.enc
+                 key_rankdep, _path_slots) = s.enc
                 if key_rankdep:
                     result = None
                     break
@@ -1236,24 +1304,10 @@ class TraceView:
             args = tuple(_resolve_rank(a, rank)
                          for a in cols.args[terminal])
             ret = _resolve_rank(cols.ret[terminal], rank)
-            # resolve iteration patterns on OFFSET-role slots (and returns),
-            # reusing the per-terminal derivation from the columns; only a
-            # rank-dependent key (RankPattern in its parts) is re-derived
+            # resolve iteration patterns on OFFSET-role slots (and returns)
+            # and on paths' fields
             if s.enc is not None:
-                key, _, _, _, off_slots, ret_is_offset, key_rankdep = s.enc
-                if key_rankdep:
-                    key = _derive_key(func_id, tidx, args, ret, roles,
-                                      ret_is_offset)
-                enc = [args[j] for j in off_slots]
-                if ret_is_offset:
-                    enc.append(ret)
-                dec = decoder.decode(key, enc)
-                args = list(args)
-                for j, v in zip(off_slots, dec):
-                    args[j] = v
-                args = tuple(args)
-                if ret_is_offset:
-                    ret = dec[-1]
+                args, ret = _decoded_args(s, args, ret, decoder, roles)
             t0 = int(ts[i, 0]) if ts is not None else None
             t1 = int(ts[i, 1]) if ts is not None else None
             yield Record(func=s.name, layer=s.layer, args=args,

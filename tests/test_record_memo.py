@@ -16,8 +16,8 @@ import pytest
 from repro.core import selftrace, streaming, trace_format
 from repro.core.apis import framework, posix, shardio
 from repro.core.cst import CST
-from repro.core.encoding import Handle, IterPattern
-from repro.core.patterns import IntraPatternTracker
+from repro.core.encoding import Handle, IterPattern, PathPattern
+from repro.core.patterns import IntraPatternTracker, split_path
 from repro.core.recorder import (_KEPT_TYPES, Recorder, RecorderConfig,
                                  attach, detach)
 from repro.core.sequitur import Sequitur, concat_grammars
@@ -49,9 +49,9 @@ def _data_bytes(spec, norm, nret):
 
 class _Reference:
     """The record path without reuse, per call: roles normalised (paths
-    filtered by prefix, handles given the smallest free id, closed ones
-    freed), offsets encoded, the signature built and interned, its
-    terminal pushed.  ``hits`` counts the calls whose signature is the
+    filtered by prefix and split into template and fields, handles given
+    the smallest free id, closed ones freed), offsets and path fields
+    encoded, the signature built and interned, its terminal pushed.  ``hits`` counts the calls whose signature is the
     one kept in their key's run: a call that continues the run (its
     values encode as ``IterPattern``s), at the depth of the last one
     that kept it, with every SIZE/VAL value (and a return that is not
@@ -97,6 +97,7 @@ class _Reference:
         spec = self.registry.spec(func_id)
         tidx = self.threads.setdefault(thread, len(self.threads))
         norm, offsets, slots, hids, parts = [], [], [], [], []
+        paths, fields = [], []
         exact = True
         for i, arg in enumerate(raw_args):
             role = spec.args[i].role if i < len(spec.args) else Role.VAL
@@ -107,8 +108,13 @@ class _Reference:
                     if spec.ret_role == Role.HANDLE and ret is not None:
                         self.untracked.add(ret)
                     return
+                template, fs = (split_path(p) if self.intra_patterns
+                                else (p, ()))
+                if fs:
+                    paths.append((len(norm), template, len(fs)))
+                    fields.extend(fs)
                 norm.append(p)
-                parts.append(p)
+                parts.append(template)
             elif role == Role.HANDLE:
                 if arg in self.untracked:
                     return
@@ -135,14 +141,20 @@ class _Reference:
         elif spec.ret_role == Role.BUF and hasattr(ret, "__len__"):
             nret = len(ret)
         ret_off = spec.ret_role == Role.OFFSET and isinstance(nret, int)
-        if offsets or ret_off:
+        if offsets or fields or ret_off:
             key_ret = ("h", nret.id) if isinstance(nret, Handle) else nret
             key = (func_id, tidx, tuple(hids), tuple(parts),
                    None if ret_off else key_ret)
             exact &= ret_off or type(nret) in _KEPT_TYPES
-            enc = self.intra.encode(key, offsets + ([nret] if ret_off else []))
+            enc = self.intra.encode(
+                key, offsets + fields + ([nret] if ret_off else []),
+                repeat_concrete=not offsets and not ret_off)
             for slot, v in zip(slots, enc):
                 norm[slot] = v
+            k = len(slots)
+            for slot, template, n in paths:
+                norm[slot] = PathPattern(template, tuple(enc[k:k + n]))
+                k += n
             if ret_off:
                 nret = enc[-1]
             if enc and not isinstance(enc[0], IterPattern):
@@ -521,6 +533,37 @@ def s_value_types(rec, tmp_path, monkeypatch):
         detach()
 
 
+def s_path_runs(rec, tmp_path, monkeypatch):
+    """Paths whose decimal fields follow runs, through the wrappers: file
+    ids counting up, one path repeated, a run broken, renames with two
+    paths of fields each, and failed calls in a run."""
+    d = tmp_path / "mdtest_tree.0"
+    ids = list(range(12)) + [3, 3, 3] + list(range(20, 30))
+    attach(rec)
+    try:
+        posix.mkdir(str(d), 0o775)
+        for i in ids:
+            fd = posix.open(str(d / f"file.mdtest.0.{i}"),
+                            os.O_CREAT | os.O_WRONLY, 0o664)
+            posix.pwrite(fd, b"m" * 64, 0)
+            posix.close(fd)
+        for i in ids:
+            posix.stat(str(d / f"file.mdtest.0.{i}"))
+        for i in range(20, 26):
+            posix.rename(str(d / f"file.mdtest.0.{i}"),
+                         str(d / f"moved.{2 * i}"))
+        for i in range(20, 26):
+            posix.unlink(str(d / f"moved.{2 * i}"))
+        for i in sorted(set(ids) - set(range(20, 26))):
+            posix.unlink(str(d / f"file.mdtest.0.{i}"))
+        for i in range(4):
+            with pytest.raises(FileNotFoundError):
+                posix.stat(str(d / f"missing.{i}"))
+        posix.rmdir(str(d))
+    finally:
+        detach()
+
+
 def s_timestamps_off(rec, tmp_path, monkeypatch):
     def calls(fds):
         _pwrite_run(fds[0], [i * 4096 for i in range(20)])
@@ -579,6 +622,9 @@ STREAMS = {
     "two_threads_facade": (s_two_threads_facade, REGISTRY, {}, True),
     "path_prefixes_untracked": (s_path_prefixes_untracked, REGISTRY, {}, True),
     "value_types": (s_value_types, REGISTRY, {}, True),
+    "path_runs": (s_path_runs, REGISTRY, {}, True),
+    "path_runs_intra_off": (s_path_runs, REGISTRY,
+                            {"intra_patterns": False}, False),
     "timestamps_off": (s_timestamps_off, REGISTRY, {"timestamps": False},
                        True),
     "spec_registered_late": (s_spec_registered_late, None, {}, True),

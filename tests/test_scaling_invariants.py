@@ -1,5 +1,6 @@
 """The paper's scaling figures as assertions (Figs 4-6, Section 5.1-5.2)."""
 
+import os
 import shutil
 import sys
 import tempfile
@@ -11,8 +12,17 @@ from benchmarks.workloads import flash_rank, ior_rank, run_ranks  # noqa: E402
 from repro.core.recorder import RecorderConfig
 
 
+def _data_dir():
+    """The same data directory for every run of one test process: paths'
+    decimal fields are part of the trace, so the compared runs must not
+    differ in a random directory name."""
+    d = os.path.join(tempfile.gettempdir(), f"repro_scaling_{os.getpid()}")
+    os.makedirs(d, exist_ok=True)
+    return d
+
+
 def _ior(nprocs, n_calls, **cfg_kw):
-    d = tempfile.mkdtemp()
+    d = _data_dir()
     try:
         return run_ranks(ior_rank, nprocs, RecorderConfig(timestamps=False,
                                                           **cfg_kw),
@@ -56,7 +66,7 @@ def test_fig5_intra_off_inter_on_constant_but_larger():
 
 
 def _flash(nprocs, iterations, **kw):
-    d = tempfile.mkdtemp()
+    d = _data_dir()
     try:
         return run_ranks(flash_rank, nprocs, RecorderConfig(timestamps=False),
                          data_dir=d, iterations=iterations, **kw)
@@ -87,7 +97,6 @@ def test_fig7_collective_tracks_aggregators():
 
 
 def test_table4_recorder_much_smaller_than_old():
-    import os
     from repro.core.baselines import RecorderOld, ToolAdapter
     d = tempfile.mkdtemp()
     try:
